@@ -35,7 +35,10 @@ ALL_SECTIONS = [
 def main() -> None:
     import jax
 
+    from repro.utils.compile_cache import enable_compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    enable_compile_cache()
 
     argv = sys.argv[1:]
     quick = "--quick" in argv

@@ -16,8 +16,8 @@ trace hook that streams per-iteration diagnostics (max violation, alpha,
 probes) to the host for the Figure-3 convergence studies.
 
 ``MWUOptions.kernel_backend`` selects the vector-op implementation for
-the loop body: under ``"pallas"`` (or ``"auto"`` on TPU) the incidence
-gathers, the eta-softmax gradient weights, every line-search probe, and
+the loop body: under ``"pallas"`` the incidence gathers (interpret mode
+only), the eta-softmax gradient weights, every line-search probe, and
 the x/y/z update triple run through the fused Pallas kernel pack via
 ``repro.kernels.dispatch`` — the entry points resolve the backend
 host-side (outside jit) into a :class:`~repro.kernels.dispatch.KernelPolicy`
@@ -85,9 +85,10 @@ class MWUOptions:
     pure: bool | None = None  # None = auto-detect single-row objective embedding
     # packing slack accepted at termination; the theory gives (1+eps).
     check_packing: bool = True
-    # vector-op backend for the loop body: "auto" (pallas on TPU, xla
-    # elsewhere; REPRO_KERNEL_BACKEND env var overrides), "pallas"
-    # (fused kernel pack, interpret mode off-TPU), or "xla".
+    # vector-op backend for the loop body: "auto" (xla everywhere until a
+    # chip measurement shows a kernel winning; REPRO_KERNEL_BACKEND env
+    # var overrides), "pallas" (fused kernel pack, interpret mode
+    # off-TPU), or "xla".
     kernel_backend: str = "auto"
 
     def resolve_pure(self, P: LinOp, C: LinOp) -> bool:

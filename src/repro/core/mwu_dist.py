@@ -25,7 +25,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..sparsela.partition import Partition2D
-from ..utils.compat import shard_map
 from ..utils.deprecation import warn_once
 from .mwu import MWUOptions, _run
 from .operators import Incidence, OnesRow
@@ -137,7 +136,7 @@ def make_pod_parallel_solver(mesh, G: int, block: int, n_vertices: int,
         return res.status[None], res.iters[None], obj[None], res.max_px[None]
 
     def fn(bounds, u, v, msk):
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P("pod"), P(), P(), P()),

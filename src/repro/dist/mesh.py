@@ -21,9 +21,7 @@ Two axes (paper §5 mapped onto SPMD):
 
 ``MeshPlan.build`` constructs the actual ``jax.sharding.Mesh`` over the
 first ``pod * data`` host devices (via :func:`repro.launch.mesh.make_mesh`),
-and :meth:`MeshPlan.shard_map` wraps :func:`repro.utils.compat.shard_map`
-so version-dependent kwargs (``check_vma``/``check_rep``) are threaded in
-one place.
+and :meth:`MeshPlan.shard_map` wraps ``jax.shard_map`` over it.
 """
 from __future__ import annotations
 
@@ -32,7 +30,6 @@ from dataclasses import dataclass
 import jax
 
 from ..launch.mesh import make_mesh
-from ..utils import compat
 
 __all__ = ["MeshPlan", "POD_AXIS", "DATA_AXIS"]
 
@@ -88,13 +85,13 @@ class MeshPlan:
         return mesh
 
     def shard_map(self, f, *, in_specs, out_specs, check_vma: bool = False):
-        """``compat.shard_map`` over this plan's mesh.
+        """``jax.shard_map`` over this plan's mesh.
 
         ``check_vma`` defaults off: the solver's replication invariants
         (constraint-space vectors re-replicate through the operator
         psums) are not expressible to the static rep checker — they are
         asserted numerically by ``tests/test_dist_solver.py`` instead.
         """
-        return compat.shard_map(
+        return jax.shard_map(
             f, mesh=self.build(), in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
         )

@@ -38,6 +38,7 @@ import numpy as np
 from ..api.solver import Solver
 from ..core.mwu import _run
 from ..kernels import dispatch as _kd
+from ..launch.mesh import sharding_for
 from ..sparsela.partition import partition_edges_1d
 from .mesh import POD_AXIS, MeshPlan
 from .shard import (
@@ -125,6 +126,24 @@ class DistSolver(Solver):
             "psum_rounds": 0,
         }
 
+    # -- placement ------------------------------------------------------
+    def place(self, problem, *, batched_problem: bool = False):
+        """``problem`` slab-padded for this plan and put on its mesh.
+
+        Each leaf goes where the launch reads it (``problem_specs``): in
+        edge_slab mode every device holds only its own edge slab. Every
+        launch places its operands this way; calling ``place`` once
+        before a ``solve`` makes the launches' puts no-ops, so the graph
+        is never copied whole onto one device. Build the problem on the
+        host (``jax.default_device(jax.devices("cpu")[0])``) for the
+        same to hold for set-up.
+        """
+        mode = pod_mode(problem)
+        if mode == "edge_slab":
+            problem, _ = slab_pad_problem(problem, self.plan.pod)
+        specs = problem_specs(problem, mode, batched_problem)
+        return jax.device_put(problem, sharding_for(self.plan.build(), specs))
+
     # -- feasibility primitives (everything else is inherited) ---------
     def _prepare_launch(self, problem, bounds, batched_problem: bool) -> dict:
         """Host-side launch prep shared by execution and AOT inspection.
@@ -166,6 +185,8 @@ class DistSolver(Solver):
 
         kernels = _kd.resolve(self.opts.kernel_backend)  # host-side, pre-jit
         specs = problem_specs(problem, mode, batched_problem)
+        if plan.n_devices > 1:
+            problem = jax.device_put(problem, sharding_for(plan.build(), specs))
         key = (
             plan,
             self.opts,

@@ -27,15 +27,19 @@ _POS = 1e30
 _NEG = -1e30
 
 
-def _axpy_kernel(n, alpha_ref, y_ref, dy_ref, out_ref, red_ref, acc_ref):
+def _axpy_kernel(n, alpha_ref, y_ref, dy_ref, out_ref, red_ref, mn_ref, mx_ref):
+    """out tile = y + alpha*dy; red rows 0/1 = [min, max] after the last tile.
+
+    The running min/max are per-lane (8, 128) vectors folded on the last
+    grid step (Mosaic cannot store a scalar to VMEM).
+    """
     i = pl.program_id(0)
-    nt = pl.num_programs(0)
-    dt = acc_ref.dtype
+    dt = mn_ref.dtype
 
     @pl.when(i == 0)
     def _init():
-        acc_ref[0] = jnp.asarray(_POS, dt)  # running min
-        acc_ref[1] = jnp.asarray(_NEG, dt)  # running max
+        mn_ref[...] = jnp.full((SUBLANES, LANES), _POS, dt)  # running min
+        mx_ref[...] = jnp.full((SUBLANES, LANES), _NEG, dt)  # running max
 
     out = y_ref[...] + alpha_ref[0] * dy_ref[...]
     idx = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0) * LANES + jax.lax.broadcasted_iota(
@@ -43,13 +47,13 @@ def _axpy_kernel(n, alpha_ref, y_ref, dy_ref, out_ref, red_ref, acc_ref):
     )
     valid = (i * TILE + idx) < n
     out_ref[...] = jnp.where(valid, out, jnp.zeros((), dt))
-    acc_ref[0] = jnp.minimum(acc_ref[0], jnp.min(jnp.where(valid, out, jnp.asarray(_POS, dt))))
-    acc_ref[1] = jnp.maximum(acc_ref[1], jnp.max(jnp.where(valid, out, jnp.asarray(_NEG, dt))))
+    mn_ref[...] = jnp.minimum(mn_ref[...], jnp.where(valid, out, jnp.asarray(_POS, dt)))
+    mx_ref[...] = jnp.maximum(mx_ref[...], jnp.where(valid, out, jnp.asarray(_NEG, dt)))
 
-    @pl.when(i == nt - 1)
+    @pl.when(i == pl.num_programs(0) - 1)
     def _fin():
-        red_ref[0] = acc_ref[0]
-        red_ref[1] = acc_ref[1]
+        row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+        red_ref[...] = jnp.where(row == 0, jnp.min(mn_ref[...]), jnp.max(mx_ref[...])).astype(dt)
 
 
 def axpy_reduce_pallas(y, dy, alpha, interpret: bool = True):
@@ -61,23 +65,17 @@ def axpy_reduce_pallas(y, dy, alpha, interpret: bool = True):
     yp = jnp.pad(y, (0, pad)).reshape(nt * SUBLANES, LANES)
     dp = jnp.pad(dy.astype(dt), (0, pad)).reshape(nt * SUBLANES, LANES)
     a = alpha.astype(dt).reshape(1)
+    tile = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
     out, red = pl.pallas_call(
         functools.partial(_axpy_kernel, n),
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile, tile],
+        out_specs=[tile, pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((nt * SUBLANES, LANES), dt),
-            jax.ShapeDtypeStruct((2,), dt),
+            jax.ShapeDtypeStruct((SUBLANES, LANES), dt),
         ],
-        scratch_shapes=[pltpu.SMEM((2,), dt)],
+        scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), dt)] * 2,
         interpret=interpret,
     )(a, yp, dp)
-    return out.reshape(-1)[:n], red[0], red[1]
+    return out.reshape(-1)[:n], red[0, 0], red[1, 0]

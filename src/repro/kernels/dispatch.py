@@ -18,13 +18,20 @@ vector op" is decided. Three layers cooperate:
    policy is pure XLA, so operators used outside a solve behave exactly
    as before.
 3. **Per-op gate** — even under a ``pallas`` policy an individual call
-   falls back to XLA when the kernel cannot serve it: gathers whose
-   vertex vector exceeds :data:`VMEM_VERTEX_LIMIT`, float64 on a real
-   TPU (no f64 VPU; interpret mode keeps f64 for CPU CI parity), or
-   masked reductions (the mask-aware paths stay on XLA — handled at the
-   call sites). Every decision is counted in :func:`stats` so tests and
+   falls back to XLA when the kernel cannot serve it: every gather on a
+   real TPU (the single-block ``incidence_gather`` kernel indexes a 1-D
+   VMEM ref, which Mosaic cannot lower: "Only 2D gather is supported"),
+   gathers whose vertex vector exceeds :data:`VMEM_VERTEX_LIMIT`,
+   float64 on a real TPU (no f64 VPU; interpret mode keeps f64 for CPU
+   CI parity), or masked reductions (the mask-aware paths stay on XLA —
+   handled at the call sites). Every decision is counted in
+   :func:`stats`, each gated fallback with its reason, so tests and
    ``benchmarks/bench_breakdown.py`` can prove the pallas path is
    active rather than silently falling back.
+
+``"auto"`` resolves to XLA on every platform, TPU included: no chip
+measurement yet shows a kernel of the pack beating XLA, so the kernels
+run only on an explicit ``"pallas"`` request.
 
 The pallas entry points are wrapped in ``jax.custom_batching.custom_vmap``
 with an XLA batch rule: ``Solver.solve_batch`` and the ``repro.lpserve``
@@ -125,8 +132,8 @@ def resolve(request: str | None = "auto") -> KernelPolicy:
 
     Precedence: an explicit ``"pallas"`` / ``"xla"`` request wins; for
     ``"auto"`` (or ``None``) the ``REPRO_KERNEL_BACKEND`` env var is
-    consulted, then the platform heuristic (pallas on TPU, xla
-    elsewhere). Call this OUTSIDE ``jax.jit`` and pass the result
+    consulted, then the default, which is xla on every platform until a
+    chip measurement shows a kernel winning. Call this OUTSIDE ``jax.jit`` and pass the result
     through as a static argument — ``jax.default_backend()`` read
     inside a traced function is frozen into the jit cache and goes
     stale when the device set changes.
@@ -136,12 +143,9 @@ def resolve(request: str | None = "auto") -> KernelPolicy:
         req = os.environ.get(ENV_VAR, "") or "auto"
     if req not in BACKENDS:
         raise ValueError(f"kernel backend must be one of {BACKENDS}, got {req!r}")
-    platform = jax.default_backend()
-    if req == "auto":
-        req = "pallas" if platform == "tpu" else "xla"
-    if req == "xla":
+    if req in ("auto", "xla"):
         return XLA_POLICY
-    return KernelPolicy("pallas", interpret=platform != "tpu")
+    return KernelPolicy("pallas", interpret=jax.default_backend() != "tpu")
 
 
 _ACTIVE: contextvars.ContextVar[KernelPolicy] = contextvars.ContextVar(
@@ -166,32 +170,51 @@ def active_policy() -> KernelPolicy:
 # -- dispatch accounting ---------------------------------------------------
 # Counts trace-time decisions per op; benchmarks and tests use this to
 # assert the pallas path is genuinely active (not silently falling back).
-_STATS: dict[str, dict[str, int]] = {}
+# A pallas request the gate sent to XLA is also counted by reason, under
+# the op's "fallback" key.
+_STATS: dict[str, dict] = {}
+
+# Why a gated op left the pallas path (keys of stats()[op]["fallback"]).
+GATHER_NO_TPU_LOWERING = "gather: 1-D VMEM gather has no Mosaic lowering"
+F64_ON_TPU = "f64: no f64 vector unit on TPU"
+OVER_VMEM = "gather: vertex vector over the VMEM limit"
 
 
-def _note(op: str, impl: str) -> None:
+def _note(op: str, impl: str, reason: str | None = None) -> None:
     d = _STATS.setdefault(op, {"pallas": 0, "xla": 0})
     d[impl] += 1
+    if reason is not None:
+        fb = d.setdefault("fallback", {})
+        fb[reason] = fb.get(reason, 0) + 1
 
 
 def reset_stats() -> None:
     _STATS.clear()
 
 
-def stats() -> dict[str, dict[str, int]]:
-    return {op: dict(d) for op, d in _STATS.items()}
+def stats() -> dict[str, dict]:
+    return {
+        op: {k: dict(v) if isinstance(v, dict) else v for k, v in d.items()}
+        for op, d in _STATS.items()
+    }
 
 
-def _gate(op: str, policy: KernelPolicy, n: int, dtype) -> str:
-    """Per-op feasibility of the pallas path, from static shape/dtype."""
+def _gate(op: str, policy: KernelPolicy, n: int, dtype) -> tuple[str, str | None]:
+    """Per-op feasibility of the pallas path, from static shape/dtype.
+
+    Returns ``(impl, reason)``: ``reason`` says why a pallas policy's op
+    had to take XLA, and is None otherwise.
+    """
     if policy.backend != "pallas":
-        return "xla"
+        return "xla", None
     dt = jnp.dtype(dtype)
     if jnp.issubdtype(dt, jnp.floating) and dt.itemsize >= 8 and not policy.interpret:
-        return "xla"  # real TPUs have no f64 vector unit
+        return "xla", F64_ON_TPU
+    if op == "gather" and not policy.interpret:
+        return "xla", GATHER_NO_TPU_LOWERING
     if op == "gather" and n > vmem_vertex_limit(dtype):
-        return "xla"  # w no longer fits VMEM single-block
-    return "pallas"
+        return "xla", OVER_VMEM  # w no longer fits VMEM single-block
+    return "pallas", None
 
 
 def choose(op: str, x) -> str:
@@ -201,8 +224,8 @@ def choose(op: str, x) -> str:
     vector for ``"gather"``, the reduced vector for ``"softmax"`` /
     ``"probe"`` / ``"axpy"``.
     """
-    impl = _gate(op, active_policy(), x.shape[0], x.dtype)
-    _note(op, impl)
+    impl, reason = _gate(op, active_policy(), x.shape[0], x.dtype)
+    _note(op, impl, reason)
     return impl
 
 
@@ -217,7 +240,7 @@ def resolve_impl(op: str, impl: str, *, n: int, dtype) -> tuple[str, bool]:
     """
     interpret = jax.default_backend() != "tpu"
     if impl == "auto":
-        impl = _gate(op, resolve("auto"), n, dtype)
+        impl, _ = _gate(op, resolve("auto"), n, dtype)
     return impl, interpret
 
 

@@ -11,9 +11,14 @@ vertex blocks, accumulating partial gathers — edges are pre-sorted by
 endpoint block by `sparsela.partition`, so each edge tile touches one
 block per endpoint).
 
-This single-block variant holds w fully in VMEM; the dispatch layer
-(`repro.kernels.dispatch.VMEM_VERTEX_LIMIT`, 3M f32 vertices — see the
-headroom math there) falls back to the XLA path beyond that. The gather
+This single-block variant holds w fully in VMEM and indexes it with
+``jnp.take`` on a 1-D ref. Mosaic cannot lower that ("Only 2D gather is
+supported"), so the kernel runs only in interpret mode: the dispatch
+gate sends every gather under a non-interpret policy to XLA and counts
+the reason (``dispatch.GATHER_NO_TPU_LOWERING``) in ``dispatch.stats()``.
+In interpret mode the gate also falls back beyond
+`repro.kernels.dispatch.VMEM_VERTEX_LIMIT` (3M f32 vertices — see the
+headroom math there). The gather
 runs in the input dtype end to end: f64 solves keep full precision
 through the kernel path (interpret mode; real TPUs gate f64 to XLA).
 """

@@ -14,10 +14,10 @@ identifies exactly this "search" vector work as 20-50% of runtime
 ``core.stepsize.make_probe_fn`` routes every probe through it when the
 dispatch layer selects the pallas backend.
 
-Online update per tile (flash-style):
-    m' = max(m, max(a));  c = exp(m - m')
-    s' = s*c + sum exp(a - m');  t' = t*c + sum exp(a - m') * dy
-(final t/s = <softmax(a), dy>, computed by the host wrapper).
+Online update per lane (flash-style, an (8, 128) vector carry):
+    m' = max(m, a);  c = exp(m - m')
+    s' = s*c + exp(a - m');  t' = t*c + exp(a - m') * dy
+and the last grid step folds the lanes into lse and t/s = <softmax(a), dy>.
 
 Arithmetic runs in the input dtype (f64 stays f64 in interpret mode).
 """
@@ -38,46 +38,53 @@ _POS = 1e30
 _NEG = -1e30
 
 
-def _probe_kernel(n, scal_ref, y_ref, dy_ref, out_ref, acc_ref):
-    """scal = [sign*eta, alpha]; out = [m, lse, t_scaled, min_v]."""
+def _probe_kernel(n, scal_ref, y_ref, dy_ref, out_ref, m_ref, s_ref, t_ref, mn_ref):
+    """scal = [sign*eta, alpha]; out rows 0/1/2 = [lse, <softmax, dy>, min_v].
+
+    The carries are per-lane (8, 128) vectors; the last grid step folds
+    the lanes and writes each result broadcast over one sublane row of
+    the (8, 128) out block (Mosaic cannot store a scalar to VMEM).
+    """
     i = pl.program_id(0)
-    nt = pl.num_programs(0)
-    dt = acc_ref.dtype
+    dt = m_ref.dtype
 
     @pl.when(i == 0)
     def _init():
-        acc_ref[0] = jnp.asarray(_NEG, dt)  # running max m
-        acc_ref[1] = jnp.asarray(0.0, dt)  # running s
-        acc_ref[2] = jnp.asarray(0.0, dt)  # running t (softmax-weighted dy)
-        acc_ref[3] = jnp.asarray(_POS, dt)  # running min of v
+        m_ref[...] = jnp.full((SUBLANES, LANES), _NEG, dt)  # running max m
+        s_ref[...] = jnp.zeros((SUBLANES, LANES), dt)  # running s
+        t_ref[...] = jnp.zeros((SUBLANES, LANES), dt)  # running t (softmax-weighted dy)
+        mn_ref[...] = jnp.full((SUBLANES, LANES), _POS, dt)  # running min of v
 
     se = scal_ref[0]
     alpha = scal_ref[1]
-    y = y_ref[...]
     dy = dy_ref[...]
-    v = y + alpha * dy
-    a = v * se
+    v = y_ref[...] + alpha * dy
     idx = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0) * LANES + jax.lax.broadcasted_iota(
         jnp.int32, (SUBLANES, LANES), 1
     )
     valid = (i * TILE + idx) < n
-    a = jnp.where(valid, a, jnp.asarray(_NEG, dt))
+    a = jnp.where(valid, v * se, jnp.asarray(_NEG, dt))
 
-    m_old, s_old, t_old = acc_ref[0], acc_ref[1], acc_ref[2]
-    m_new = jnp.maximum(m_old, jnp.max(a))
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, a)
     c = jnp.exp(m_old - m_new)
-    e = jnp.exp(a - m_new)
-    acc_ref[0] = m_new
-    acc_ref[1] = s_old * c + jnp.sum(e)
-    acc_ref[2] = t_old * c + jnp.sum(e * jnp.where(valid, dy, jnp.zeros((), dt)))
-    acc_ref[3] = jnp.minimum(acc_ref[3], jnp.min(jnp.where(valid, v, jnp.asarray(_POS, dt))))
+    e = jnp.where(valid, jnp.exp(a - m_new), jnp.zeros((), dt))
+    m_ref[...] = m_new
+    s_ref[...] = s_ref[...] * c + e
+    t_ref[...] = t_ref[...] * c + e * dy
+    mn_ref[...] = jnp.minimum(mn_ref[...], jnp.where(valid, v, jnp.asarray(_POS, dt)))
 
-    @pl.when(i == nt - 1)
+    @pl.when(i == pl.num_programs(0) - 1)
     def _fin():
-        out_ref[0] = acc_ref[0]
-        out_ref[1] = acc_ref[0] + jnp.log(acc_ref[1])  # lse
-        out_ref[2] = acc_ref[2] / acc_ref[1]  # <softmax, dy>
-        out_ref[3] = acc_ref[3]  # min(y + alpha dy)
+        m = m_ref[...]
+        mx = jnp.max(m)
+        c = jnp.exp(m - mx)
+        s = jnp.sum(s_ref[...] * c)
+        lse = mx + jnp.log(s)
+        slope = jnp.sum(t_ref[...] * c) / s
+        mn = jnp.min(mn_ref[...])
+        row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+        out_ref[...] = jnp.where(row == 0, lse, jnp.where(row == 1, slope, mn)).astype(dt)
 
 
 def linesearch_probe_pallas(y, dy, alpha, eta, sign: float = 1.0, interpret: bool = True):
@@ -89,17 +96,14 @@ def linesearch_probe_pallas(y, dy, alpha, eta, sign: float = 1.0, interpret: boo
     yp = jnp.pad(y, (0, pad)).reshape(nt * SUBLANES, LANES)
     dp = jnp.pad(dy.astype(dt), (0, pad)).reshape(nt * SUBLANES, LANES)
     scal = jnp.stack([jnp.asarray(sign, dt) * eta.astype(dt), alpha.astype(dt)])
+    tile = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         functools.partial(_probe_kernel, n),
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((4,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((4,), dt),
-        scratch_shapes=[pltpu.SMEM((4,), dt)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile, tile],
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, LANES), dt),
+        scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), dt)] * 4,
         interpret=interpret,
     )(scal, yp, dp)
-    return out[1], out[2], out[3]
+    return out[0, 0], out[1, 0], out[2, 0]

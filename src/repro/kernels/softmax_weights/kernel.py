@@ -8,8 +8,10 @@ Computes, in two HBM sweeps over a length-n vector v:
 which yields both smax_eta/smin_eta (= sign * lse / eta) and the MWU
 weight vector grad smax/smin in one fused pipeline — the paper fuses
 exactly this gradient computation on CPU with OpenMP + AVX-512; on TPU
-the tile is an (8, 128)-aligned VMEM block and the reduction carry lives
-in SMEM scratch across a sequential 1-D grid.
+the tile is an (8, 128)-aligned VMEM block and the reduction carry is a
+per-lane (8, 128) VMEM scratch across a sequential 1-D grid. Scalars
+(sign*eta, lse) enter through SMEM; the lse result leaves as a
+broadcast (8, 128) block, since Mosaic cannot store a scalar to VMEM.
 
 All arithmetic runs in the input dtype (f32 or, in interpret mode, f64 —
 the dispatch gate keeps f64 off real TPUs), so kernel and XLA paths
@@ -34,49 +36,50 @@ TILE = SUBLANES * LANES  # 1024 elements per VMEM tile
 _NEG = -1e30
 
 
-def _reduce_kernel(n, se_ref, v_ref, out_ref, acc_ref):
-    """Pass 1: running (max m, sum s) over tiles; writes [m, lse] at the end."""
-    i = pl.program_id(0)
-    nt = pl.num_programs(0)
-    dt = acc_ref.dtype
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[0] = jnp.asarray(_NEG, dt)  # running max
-        acc_ref[1] = jnp.asarray(0.0, dt)  # running sum (scaled by exp(-m))
-
-    a = v_ref[...] * se_ref[0]
+def _valid(i, n):
+    """Mask of the tile's lanes whose global index is below ``n``."""
     idx = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0) * LANES + jax.lax.broadcasted_iota(
         jnp.int32, (SUBLANES, LANES), 1
     )
-    valid = (i * TILE + idx) < n
-    a = jnp.where(valid, a, jnp.asarray(_NEG, dt))
+    return (i * TILE + idx) < n
 
-    m_old = acc_ref[0]
-    s_old = acc_ref[1]
-    m_tile = jnp.max(a)
-    m_new = jnp.maximum(m_old, m_tile)
-    corr = jnp.exp(m_old - m_new)
-    s_new = s_old * corr + jnp.sum(jnp.exp(a - m_new))
-    acc_ref[0] = m_new
-    acc_ref[1] = s_new
 
-    @pl.when(i == nt - 1)
+def _reduce_kernel(n, se_ref, v_ref, out_ref, m_ref, s_ref):
+    """Pass 1: per-lane running (max m, sum s); writes lse at the end.
+
+    The carries are (8, 128) vectors, one online logsumexp per lane, so
+    no scalar ever lives in VMEM; the last step folds the lanes and
+    broadcasts lse over the (8, 128) out block.
+    """
+    i = pl.program_id(0)
+    dt = m_ref.dtype
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full((SUBLANES, LANES), _NEG, dt)
+        s_ref[...] = jnp.zeros((SUBLANES, LANES), dt)
+
+    valid = _valid(i, n)
+    a = jnp.where(valid, v_ref[...] * se_ref[0], jnp.asarray(_NEG, dt))
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, a)
+    e = jnp.where(valid, jnp.exp(a - m_new), jnp.zeros((), dt))
+    s_ref[...] = s_ref[...] * jnp.exp(m_old - m_new) + e
+    m_ref[...] = m_new
+
+    @pl.when(i == pl.num_programs(0) - 1)
     def _fin():
-        out_ref[0] = m_new
-        out_ref[1] = m_new + jnp.log(s_new)  # lse
+        m = m_ref[...]
+        mx = jnp.max(m)
+        lse = mx + jnp.log(jnp.sum(s_ref[...] * jnp.exp(m - mx)))
+        out_ref[...] = jnp.full((SUBLANES, LANES), lse, dt)
 
 
 def _normalize_kernel(n, se_ref, v_ref, lse_ref, w_ref):
     """Pass 2: w = exp(sign*eta*v - lse), zero on padded lanes."""
-    i = pl.program_id(0)
     a = v_ref[...] * se_ref[0]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0) * LANES + jax.lax.broadcasted_iota(
-        jnp.int32, (SUBLANES, LANES), 1
-    )
-    valid = (i * TILE + idx) < n
-    w = jnp.exp(a - lse_ref[1])
-    w_ref[...] = jnp.where(valid, w, jnp.zeros((), w.dtype)).astype(w_ref.dtype)
+    w = jnp.exp(a - lse_ref[0])
+    w_ref[...] = jnp.where(_valid(pl.program_id(0), n), w, jnp.zeros((), w.dtype)).astype(w_ref.dtype)
 
 
 def softmax_weights_pallas(v, eta, sign: float = 1.0, interpret: bool = True):
@@ -87,29 +90,26 @@ def softmax_weights_pallas(v, eta, sign: float = 1.0, interpret: bool = True):
     vp = jnp.pad(v, (0, nt * TILE - n)).reshape(nt * SUBLANES, LANES)
     se = (jnp.asarray(sign, dt) * eta.astype(dt)).reshape(1)
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tile = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
+
     stats = pl.pallas_call(
         functools.partial(_reduce_kernel, n),
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((2,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((2,), dt),
-        scratch_shapes=[pltpu.SMEM((2,), dt)],
+        in_specs=[smem, tile],
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, LANES), dt),
+        scratch_shapes=[pltpu.VMEM((SUBLANES, LANES), dt)] * 2,
         interpret=interpret,
     )(se, vp)
+    lse = stats[0, 0]
 
     w = pl.pallas_call(
         functools.partial(_normalize_kernel, n),
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
+        in_specs=[smem, tile, smem],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((nt * SUBLANES, LANES), dt),
         interpret=interpret,
-    )(se, vp, stats)
-    return stats[1], w.reshape(-1)[:n]
+    )(se, vp, lse.reshape(1))
+    return lse, w.reshape(-1)[:n]

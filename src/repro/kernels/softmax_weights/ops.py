@@ -26,7 +26,7 @@ def softmax_weights(v, eta, sign: float = 1.0, impl: str = "auto"):
     """(lse, w): lse = logsumexp(sign*eta*v); w = softmax(sign*eta*v).
 
     smax_eta(v) = lse/eta (sign=+1); smin_eta(v) = -lse/eta (sign=-1).
-    impl: "auto" (pallas on TPU, xla elsewhere) | "pallas" | "xla".
+    impl: "auto" (the dispatch default, xla) | "pallas" | "xla".
     """
     impl, interpret = resolve_impl("softmax", impl, n=v.shape[0], dtype=v.dtype)
     return _softmax_weights_jit(v, eta, sign, impl, interpret)

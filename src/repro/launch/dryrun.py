@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-#   This flag lives ONLY here (dry-run); tests/benches see 1 device.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each runnable cell (see configs.shapes.skip_reason) this driver:
@@ -17,7 +12,17 @@ Usage:
   python -m repro.launch.dryrun --arch yi-34b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all --mesh both
   python -m repro.launch.dryrun --list
+
+Run as a script, it fabricates 512 host devices through XLA_FLAGS before
+JAX starts a backend. Importing the module sets nothing, so no importer
+(and no chip path) inherits the flag.
 """
+import os
+
+if __name__ == "__main__":
+    # must precede the backend's start: jax fixes the device count then
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import json
 import time
@@ -34,7 +39,6 @@ from ..models import Model
 from ..models.common import DP
 from ..train.optimizer import AdamWConfig, init_opt_state, opt_state_spec
 from ..train.step import TrainState, make_train_step
-from ..utils.compat import shard_map
 from ..utils.hlo import analyze_hlo
 from ..utils.roofline import roofline_terms, model_flops_estimate
 from .mesh import make_production_mesh, sharding_for
@@ -216,7 +220,7 @@ def run_mwu_cell(mesh_kind: str, scale: int = 22, edgefactor: int = 16):
                     x, *rest = out
                     return (x[None, None], *rest)
 
-                return shard_map(
+                return jax.shard_map(
                     inner, mesh=mesh,
                     in_specs=(P("data", "model", None),) * 4,
                     out_specs=(P("data", "model", None), P(), P(), P(), P(), P()),

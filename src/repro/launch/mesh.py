@@ -12,43 +12,27 @@ import jax
 __all__ = ["make_production_mesh", "make_mesh", "sharding_for"]
 
 
-def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer
-    jax; older releases default every axis to Auto anyway, so omitting
-    the kwarg is semantically identical there.
-
-    ``devices`` selects an explicit device subset (e.g. the first
-    ``pod * data`` of ``jax.devices()`` for a :class:`repro.dist.MeshPlan`
-    smaller than the host); ``jax.make_mesh`` has no stable cross-version
-    spelling for that, so a subset goes through ``jax.sharding.Mesh``
-    directly (fine on host/CPU devices — the perf-aware reordering
-    ``jax.make_mesh`` adds only matters on real TPU topologies).
-    """
-    if devices is not None:
-        import numpy as np
-
-        devs = np.asarray(devices, dtype=object).reshape(tuple(shape))
-        return jax.sharding.Mesh(devs, tuple(axes))
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    return jax.make_mesh(
-        tuple(shape), tuple(axes), axis_types=(axis_type.Auto,) * len(axes)
-    )
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """(16,16) single pod (256 chips) or (2,16,16) two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes, devices=None):
-    """Arbitrary mesh (tests use (1,1) / (2,2) / (2,4) host-device meshes)."""
-    return _make_mesh(shape, axes, devices)
+    """``jax.make_mesh`` with every axis Auto.
+
+    ``devices`` selects an explicit device subset (e.g. the first
+    ``pod * data`` of ``jax.devices()`` for a :class:`repro.dist.MeshPlan`
+    smaller than the host); ``jax.make_mesh`` orders it for the chips'
+    physical topology.
+    """
+    return jax.make_mesh(
+        tuple(shape),
+        tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def sharding_for(mesh, spec_tree):

@@ -22,9 +22,9 @@ Canonicalization (:func:`canonical_tokens`): alpha-rename variables in
 order of first appearance, render avals as ``dtype[shape]``, sort the
 operands of commutative primitives, drop trace-incidental params
 (names, source info, unhashable backend objects), and flatten nested
-jaxprs (while bodies, branches, pjit calls) into the token stream with
+jaxprs (while bodies, branches, jit calls) into the token stream with
 structural brackets so a sequence diff aligns loop bodies. Call-like
-wrapper eqns that are the *sole* content of a jaxpr (``pjit`` around
+wrapper eqns that are the *sole* content of a jaxpr (``jit`` around
 ``shard_map`` around the body, from jitting) are unwrapped first, which
 is what lets the mesh-wrapped DistSolver program be compared op-for-op
 against the plain Solver body.
@@ -33,7 +33,7 @@ Diffing comes in two granularities. :func:`diff_tokens` aligns flat
 token streams (``difflib.SequenceMatcher``) — exact, used for the
 all-or-nothing dist-identity check. :func:`hierarchical_regions` aligns
 eqn *headers* level by level and recurses into matched containers
-(while bodies, cond branches, pjit shells), so a divergence deep inside
+(while bodies, cond branches, jit shells), so a divergence deep inside
 a loop body is scoped to that body instead of derailing the global
 alignment — that is what lets :func:`check_backend_parity` classify
 each divergence by its deep primitive content. Both report through the
@@ -68,8 +68,8 @@ BACKEND_PARITY_RULE = "jaxpr-parity-backend"
 _COMMUTATIVE = frozenset({"add", "mul", "max", "min", "and", "or", "xor", "add_any"})
 
 # call-like wrappers that are transparent when they are a jaxpr's sole
-# content: jitting adds a pjit shell, DistSolver adds a shard_map shell
-_TRANSPARENT_WRAPPERS = frozenset({"pjit", "shard_map", "closed_call", "core_call", "remat2", "custom_vmap_call"})
+# content: jitting adds a jit shell, DistSolver adds a shard_map shell
+_TRANSPARENT_WRAPPERS = frozenset({"jit", "shard_map", "closed_call", "core_call", "remat2", "custom_vmap_call"})
 
 # params that vary per trace without changing the program
 _DROP_PARAMS = frozenset({
@@ -77,6 +77,7 @@ _DROP_PARAMS = frozenset({
     "in_shardings", "out_shardings", "in_layouts", "out_layouts",
     "resource_env", "compiler_options_kvs", "ctx_mesh", "mesh",
     "name_and_src_info", "debug_info", "interpret", "backend", "device",
+    "sharding",
 })
 
 _DISPATCH_PRIMS = frozenset({"pallas_call", "custom_vmap_call"})
@@ -87,7 +88,7 @@ def _jaxpr_of(x):
 
 
 def _unwrap(jaxpr):
-    """Descend through sole-eqn transparent wrappers (pjit/shard_map shells)."""
+    """Descend through sole-eqn transparent wrappers (jit/shard_map shells)."""
     jaxpr = _jaxpr_of(jaxpr)
     while len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name in _TRANSPARENT_WRAPPERS:
         eqn = jaxpr.eqns[0]
@@ -237,7 +238,7 @@ def _finding(rule, artifact, message, *, key="", severity=ERROR, **detail) -> Fi
 def check_dist_identity(jaxpr_solver, jaxpr_dist, artifact: str) -> list[Finding]:
     """Prove an identity-plan DistSolver trace ≡ the plain Solver trace.
 
-    Both jaxprs are canonicalized (the dist side's pjit/shard_map shells
+    Both jaxprs are canonicalized (the dist side's jit/shard_map shells
     unwrap) and must be token-for-token equal; any divergence is an
     error finding carrying the first few divergent regions.
     """
@@ -266,7 +267,7 @@ def check_dist_identity(jaxpr_solver, jaxpr_dist, artifact: str) -> list[Finding
 # Their level-header deliberately drops invars and const-count params:
 # the pallas path changes which closure consts a loop body captures, but
 # the carried state (outvars) must match for the loops to be "the same
-# loop". Transparent containers (pjit shells jnp emits, cond branches of
+# loop". Transparent containers (jit shells jnp emits, cond branches of
 # one op's implementation, custom_vmap wrappers) are not structural by
 # themselves — only their *deep* content (loops, collectives, callbacks)
 # is held against a region.

@@ -197,6 +197,20 @@ def reachable(comps: dict[str, Computation], root: str) -> set[str]:
     return seen
 
 
+def _fuses_compare(comps: dict[str, Computation], op: Op) -> bool:
+    """A ``fusion`` whose fused computation holds a compare.
+
+    XLA wraps a loop condition's compare in a fusion (``%wrapped_compare``)
+    whose operands are the induction variable and the bound constant, so
+    the fusion stands for the compare in the caller.
+    """
+    return op.kind == "fusion" and any(
+        o.kind == "compare"
+        for cn in op.called_comps()
+        for o in comps.get(cn, Computation(cn)).ops
+    )
+
+
 def trip_count(comps: dict[str, Computation], cond_name: str) -> int | None:
     """Loop bound recovered from a ``while`` condition computation.
 
@@ -217,7 +231,7 @@ def trip_count(comps: dict[str, Computation], cond_name: str) -> int | None:
     for cn in reachable(comps, cond_name):
         comp = comps[cn]
         for op in comp.ops:
-            if op.kind != "compare":
+            if op.kind != "compare" and not _fuses_compare(comps, op):
                 continue
             stack = list(op.operands)
             seen: set[str] = set()

@@ -9,7 +9,7 @@ only compiled-artifact facts (trip constants, f64 op survival,
 custom-call targets) run on the HLO text IR (:mod:`.hlo_ir`).
 
 The central helper is :func:`iter_eqns`, a recursive walk over every
-equation in a jaxpr nest — through ``pjit`` bodies, ``cond`` branches,
+equation in a jaxpr nest — through ``jit`` bodies, ``cond`` branches,
 ``shard_map``/``custom_vmap_call`` call jaxprs, and ``while`` loops —
 tagging each equation with whether it sits inside a ``while`` body or
 condition (the solver's hot loop).
@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from typing import Iterator
 
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = [
     "iter_eqns",

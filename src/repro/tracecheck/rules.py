@@ -365,14 +365,12 @@ class VmemFootprintRule(Rule):
             return None
         total = 0
         for bm in getattr(gm, "block_mappings", ()):
-            block = [int(b) for b in bm.block_shape if isinstance(b, int) or getattr(b, "__index__", None)]
-            sds = getattr(bm, "array_shape_dtype", None)
-            if sds is None:
-                continue
-            nbytes = math.prod(block) * jnp.dtype(sds.dtype).itemsize if block else jnp.dtype(sds.dtype).itemsize
+            block = tuple(int(d) for d in bm.transformed_block_aval.shape)
+            array = bm.array_aval
+            nbytes = math.prod(block) * jnp.dtype(array.dtype).itemsize
             # full-array blocks are VMEM-resident once; streamed tiles are
             # double-buffered by the Mosaic pipeline
-            resident = tuple(block) == tuple(int(d) for d in sds.shape)
+            resident = block == tuple(int(d) for d in array.shape)
             total += nbytes if resident else 2 * nbytes
         return total
 

@@ -5,7 +5,7 @@ Two tiers, mirroring tests/test_distributed.py's isolation rule:
 * in-process: ``MeshPlan(1, 1)`` runs on the session's single device and
   must be BIT-identical to the plain ``Solver`` (the identity-plan
   contract), plus host-side plumbing (plan validation, mode selection,
-  compat kwargs).
+  ``MeshPlan.shard_map`` kwargs).
 * subprocess: each multi-device test spawns a fresh python with
   ``--xla_force_host_platform_device_count`` so the main session keeps
   its single device; pod-sharded runs are compared to the single-device
@@ -162,21 +162,20 @@ def test_slab_pad_problem():
 
 
 def test_compat_shard_map_kwargs():
-    """Both check_vma and the legacy check_rep spelling are accepted."""
+    """MeshPlan.shard_map runs with the replication check on, off and at its default."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     from repro.dist import MeshPlan
-    from repro.utils import compat
 
-    mesh = MeshPlan(1, 1).build()
+    plan = MeshPlan(1, 1)
 
     def body(x):
         return x * 2
 
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        f = compat.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(), **kw)
+    for kw in ({"check_vma": False}, {"check_vma": True}, {}):
+        f = plan.shard_map(body, in_specs=(P(),), out_specs=P(), **kw)
         out = jax.jit(f)(jnp.arange(4.0))
         np.testing.assert_array_equal(np.asarray(out), np.arange(4.0) * 2)
 
@@ -231,22 +230,67 @@ def test_multi_device_parity():
         devices=8,
     )
     rows = json.loads(out.strip().splitlines()[-1])
+    eps = 0.1  # MWUOptions() default
     for name, d in rows.items():
         assert d["dst_status"] == d["ref_status"], name
-        np.testing.assert_allclose(
-            d["dst_max_px"], d["ref_max_px"], rtol=5e-3, atol=5e-3, err_msg=name
-        )
-        np.testing.assert_allclose(
-            d["dst_min_cx"], d["ref_min_cx"], rtol=5e-3, atol=5e-3, err_msg=name
-        )
         got = np.asarray(d["recheck"])
         np.testing.assert_allclose(got[:, 0], d["dst_max_px"], rtol=1e-4, atol=1e-5, err_msg=name)
         np.testing.assert_allclose(got[:, 1], d["dst_min_cx"], rtol=1e-4, atol=1e-5, err_msg=name)
+        for j, status in enumerate(d["dst_status"]):
+            if status == 1:  # Status.FEASIBLE
+                # The pod psums re-associate sums, so a feasible lane's
+                # trajectory forks from the oracle's by rounding alone: it
+                # starts 1e-15 apart and the gap grows ~10x every 5
+                # iterations (test_column_slab_tracks_oracle_until_rounding),
+                # so the lanes exit at different points (vcover-pod8:
+                # max_px 0.957 vs 0.968). What both must meet is the
+                # certificate, recomputed from x: Px <= 1+eps and Cx >= 1.
+                assert got[j, 0] <= 1.0 + eps + 1e-5, (name, j, got[j])
+                assert got[j, 1] >= 1.0 - 1e-5, (name, j, got[j])
+            else:
+                np.testing.assert_allclose(
+                    d["dst_max_px"][j], d["ref_max_px"][j], rtol=5e-3, atol=5e-3, err_msg=name
+                )
+                np.testing.assert_allclose(
+                    d["dst_min_cx"][j], d["ref_min_cx"][j], rtol=5e-3, atol=5e-3, err_msg=name
+                )
     # the pure data fan-out runs the same per-lane program (unbatched on
     # each device vs vmapped in the oracle) — certificates must agree to
     # f32 fusion round-off, an order tighter than pod trajectory noise
     d = rows["match-data8"]
     np.testing.assert_allclose(d["dst_max_px"], d["ref_max_px"], rtol=1e-4)
+
+
+def test_column_slab_tracks_oracle_until_rounding():
+    """vcover on a pod=8 column slab follows the single-device trajectory
+    to f64 round-off over the first iterations: the two differ only by the
+    order of the psum'd sums, and that gap grows slowly (no slab bug)."""
+    out = run_sub(
+        """
+        import json
+        import jax
+        jax.config.update("jax_enable_x64", True)
+        import numpy as np
+        from repro.api import MWUOptions, Solver
+        from repro.dist import DistSolver, MeshPlan
+        from repro.graphs.generators import erdos
+        from repro.graphs.problems import vcover_lp
+
+        prob = vcover_lp(erdos(60, 201, seed=1))
+        gaps = []
+        for it in (1, 5, 10):
+            o = MWUOptions(max_iter=it)
+            ref = np.asarray(Solver(o).solve_batch(prob, [40.0]).x)
+            dst = np.asarray(DistSolver(o, plan=MeshPlan(pod=8, data=1)).solve_batch(prob, [40.0]).x)
+            gaps.append(float(np.abs(ref - dst).max() / np.abs(ref).max()))
+        print(json.dumps(gaps))
+        """,
+        devices=8,
+    )
+    gaps = json.loads(out.strip().splitlines()[-1])
+    assert gaps[0] < 1e-14, gaps  # one iteration: round-off only
+    assert gaps[2] < 1e-9, gaps  # ten iterations: still far below any bug
+    assert gaps[0] <= gaps[1] <= gaps[2], gaps  # the gap only grows
 
 
 def test_lpserve_mesh_sharded_lanes():
