@@ -127,7 +127,7 @@ class DistSolver(Solver):
         }
 
     # -- placement ------------------------------------------------------
-    def place(self, problem, *, batched_problem: bool = False):
+    def place(self, problem):
         """``problem`` slab-padded for this plan and put on its mesh.
 
         Each leaf goes where the launch reads it (``problem_specs``): in
@@ -141,7 +141,7 @@ class DistSolver(Solver):
         mode = pod_mode(problem)
         if mode == "edge_slab":
             problem, _ = slab_pad_problem(problem, self.plan.pod)
-        specs = problem_specs(problem, mode, batched_problem)
+        specs = problem_specs(problem, mode, batched=False)
         return jax.device_put(problem, sharding_for(self.plan.build(), specs))
 
     # -- feasibility primitives (everything else is inherited) ---------

@@ -133,10 +133,10 @@ def resolve(request: str | None = "auto") -> KernelPolicy:
     Precedence: an explicit ``"pallas"`` / ``"xla"`` request wins; for
     ``"auto"`` (or ``None``) the ``REPRO_KERNEL_BACKEND`` env var is
     consulted, then the default, which is xla on every platform until a
-    chip measurement shows a kernel winning. Call this OUTSIDE ``jax.jit`` and pass the result
-    through as a static argument — ``jax.default_backend()`` read
-    inside a traced function is frozen into the jit cache and goes
-    stale when the device set changes.
+    chip measurement shows a kernel winning. Call this OUTSIDE
+    ``jax.jit`` and pass the result through as a static argument —
+    ``jax.default_backend()`` read inside a traced function is frozen
+    into the jit cache and goes stale when the device set changes.
     """
     req = request or "auto"
     if req == "auto":

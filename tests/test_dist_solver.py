@@ -335,6 +335,38 @@ def test_lpserve_mesh_sharded_lanes():
     assert mesh["psum_rounds"] > 0  # pod sharding actually communicated
 
 
+def test_place_puts_one_edge_slab_per_device():
+    """DistSolver.place leaves each device only its own edge slab, and a
+    solve of the placed problem equals one of the problem as built."""
+    out = run_sub(
+        """
+        import json
+        import numpy as np
+        from repro.api import MWUOptions
+        from repro.dist import DistSolver, MeshPlan
+        from repro.graphs.generators import erdos
+        from repro.graphs.problems import matching_lp
+
+        prob = matching_lp(erdos(60, 201, seed=1))
+        solver = DistSolver(MWUOptions(), plan=MeshPlan(pod=4, data=1))
+        placed = solver.place(prob)
+        ref = solver.solve(prob)
+        sol = solver.solve(placed)
+        print(json.dumps({
+            "u_shards": sorted(s.data.shape[0] for s in placed.P.u.addressable_shards),
+            "u_devices": len({s.device for s in placed.P.u.addressable_shards}),
+            "n_vars": int(placed.n_vars),
+            "objective": [ref.objective, sol.objective], "x_len": len(sol.x),
+        }))
+        """,
+        devices=4,
+    )
+    d = json.loads(out.strip().splitlines()[-1])
+    assert d["u_shards"] == [51] * 4 and d["u_devices"] == 4  # 201 edges padded to 204
+    assert d["n_vars"] == 201 and d["x_len"] == 201
+    assert d["objective"][0] == d["objective"][1]
+
+
 def test_pallas_pack_active_under_shard_map():
     """The no-vmap fast path keeps the fused Pallas kernels (interpret
     mode on CPU) on the hot path inside shard_map — the custom_vmap XLA
