@@ -13,20 +13,22 @@ Phases, each printed as one ``phase=... key=value ...`` line:
   vcover at scale 12, or both at ``--scale``, through ``Solver.solve``
   (batch_width 4): set-up, first-call and warm seconds, iteration
   counts, status, peak device bytes, and the certificate rechecked in
-  numpy f64 on the host. The default scales keep the whole script well
-  inside 20 minutes on one v5e; at scale 20, match's first and warm
-  solves did not finish within 43 minutes there (ROADMAP §1.4).
+  numpy f64 on the host. These are the largest scales measured to fit
+  the script's 20 minutes on one v5e: match's first solve at scale 18
+  had not finished after 24 minutes there, and vcover's bound search
+  takes several times match's iterations (ROADMAP §1.4).
 * ``serving`` — mixed-size requests through ``LPEngine.solve_many``, which
   must match sequential ``Solver.solve``.
 * ``dispatch`` — the kernel-dispatch counters; no op may have run a Pallas
   kernel in interpret mode.
 
 ``--four-chips`` runs only ``DistSolver(plan=MeshPlan(pod=4))`` match on
-Graph500 (scale 16, or ``--scale``; edge slabs placed one per chip)
-against the one-chip ``Solver`` on the same graph, and prints each
-chip's peak bytes.
+Graph500 (scale 16, or ``--scale``; the problem is built in host memory
+and the solver puts one edge slab on each chip) against the one-chip
+``Solver`` on the same graph, and prints each chip's peak bytes.
 
 Everything runs in f32 (x64 stays off). Any failed check exits non-zero.
+The ``done`` line gives the script's seconds from the device check on.
 The last line of standard output is the JSON verdict
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 """
@@ -45,9 +47,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
 EPS = 0.1
 TOL = 1e-3  # certificate slack for f32 solves, checked in f64
-# Graph500 scale per family of the real-size phase: vcover's bound search
-# takes ~6x match's batched iterations (2502 at scale 12 on a CPU, f32)
+# Graph500 scale per family of the real-size phase (see the docstring)
 REAL_SCALES = {"match": 16, "vcover": 12}
+FOUR_CHIP_SCALE = 16
 
 
 class SmokeFailure(Exception):
@@ -253,12 +255,11 @@ def phase_four_chips(scale: int) -> None:
     dist = DistSolver(solver_opts(), plan=MeshPlan(pod=4), batch_width=4)
     with _on_host():
         problem = build("match", g)
-        placed = dist.place(problem)
     greedy = problem.lo
     report("four_chips", graph=g.name, vertices=g.n, edges=g.m, setup_s=time.perf_counter() - t0)
 
-    sol4, first_s = timed_solve(dist, placed)
-    sol4, warm_s = timed_solve(dist, placed)
+    sol4, first_s = timed_solve(dist, problem)
+    sol4, warm_s = timed_solve(dist, problem)
     peaks = {str(d.id): peak_bytes(d) for d in devices[:4]}
     report(
         "four_chips", plan="pod4", mode="edge_slab", first_call_s=first_s, warm_s=warm_s,
@@ -285,7 +286,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--scale", type=int, default=None,
                     help="Graph500 scale of every real-size family "
-                         f"(default {REAL_SCALES}; match's with --four-chips)")
+                         f"(default {REAL_SCALES}; {FOUR_CHIP_SCALE} with --four-chips)")
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the four-chip DistSolver phase and its one-chip comparison")
     args = ap.parse_args(argv)
@@ -300,11 +301,12 @@ def main(argv=None) -> int:
 
     from repro.utils.compile_cache import enable_compile_cache
 
+    t0 = time.perf_counter()
     report("device", platform=dev.platform, kind=dev.device_kind, count=len(devices),
            compile_cache=enable_compile_cache(), x64=jax.config.jax_enable_x64)
     try:
         if args.four_chips:
-            phase_four_chips(args.scale or REAL_SCALES["match"])
+            phase_four_chips(args.scale or FOUR_CHIP_SCALE)
         else:
             phase_small()
             scales = {f: args.scale or s for f, s in REAL_SCALES.items()}
@@ -314,6 +316,7 @@ def main(argv=None) -> int:
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
+    report("done", total_s=time.perf_counter() - t0)
     print(json.dumps({
         "ok": True,
         "device": {"platform": dev.platform, "kind": dev.device_kind, "count": len(devices)},

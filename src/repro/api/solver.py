@@ -385,18 +385,15 @@ def not_found_solution(problem, bound, res, stats, traces=None) -> Solution:
 def certify_solution(problem, best, best_bound, stats, traces=None) -> Solution:
     """Rescale the raw MWU point into a certified solution (§2.2)."""
     x = np.asarray(best.x)
-    # a problem slab-padded by DistSolver.place carries trailing padded
-    # variables (zero objective) that the returned x no longer has
-    c = None if problem.c is None else np.asarray(problem.c)[: x.shape[-1]]
     if problem.sense == "max":
         # Px <= 1+eps: dividing by the overshoot certifies Px <= 1
         # at an objective loss of at most (1+eps).
         x = x / max(float(best.max_px), 1.0)
-        objective = float(np.dot(c, x))
+        objective = float(np.dot(np.asarray(problem.c), x))
     elif problem.bound_mode == "objective_packing":
         # covering slack is free objective: x/min(Cx) stays feasible
         x = x / max(float(best.min_cx), 1.0)
-        objective = float(np.dot(c, x))
+        objective = float(np.dot(np.asarray(problem.c), x))
     else:
         # densest-style: the bound itself is the certified objective
         objective = float(best_bound)

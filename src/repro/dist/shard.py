@@ -188,17 +188,14 @@ def slab_pad_problem(problem: Problem, pod: int) -> tuple[Problem, int]:
     appended at the global end so contiguous pod slabs reassemble into
     padded-global order and ``x[..., :n_edges]`` strips them. Returns
     ``(padded problem, original edge count)``; with ``pod == 1`` the
-    problem is returned untouched (bit-parity with the vmap path). The
-    original count is ``problem.n_vars`` where the builder set it, so a
-    problem padded once (``DistSolver.place``) pads to itself and still
-    reports its true width.
+    problem is returned untouched (bit-parity with the vmap path).
     """
     P = problem.P
     n_edges = int(jnp.shape(P.u)[-1])
     padded, _ = partition_edges_1d(n_edges, pod)
     pad = padded - n_edges
     if pad == 0:
-        return problem, int(problem.n_vars) or n_edges
+        return problem, n_edges
     mask = P.edge_mask
     if mask is None:
         mask = jnp.ones(jnp.shape(P.u), bool)
@@ -233,8 +230,8 @@ def problem_specs(problem: Problem, mode: str, batched: bool):
     axis of the edge-aligned leaves additionally shards over ``pod``.
     Every other leaf is replicated (constraint-space masks, bounds,
     column-mode operators). The result feeds ``shard_map`` in_specs and,
-    via :func:`repro.launch.mesh.sharding_for`, the ``device_put`` of
-    ``DistSolver.place``.
+    via :func:`repro.launch.mesh.sharding_for`, the ``device_put`` with
+    which ``DistSolver`` places each problem on the mesh.
     """
     lead = (DATA_AXIS,) if batched else ()
 

@@ -125,24 +125,10 @@ class DistSolver(Solver):
             "mwu_iters": 0,
             "psum_rounds": 0,
         }
-
-    # -- placement ------------------------------------------------------
-    def place(self, problem):
-        """``problem`` slab-padded for this plan and put on its mesh.
-
-        Each leaf goes where the launch reads it (``problem_specs``): in
-        edge_slab mode every device holds only its own edge slab. Every
-        launch places its operands this way; calling ``place`` once
-        before a ``solve`` makes the launches' puts no-ops, so the graph
-        is never copied whole onto one device. Build the problem on the
-        host (``jax.default_device(jax.devices("cpu")[0])``) for the
-        same to hold for set-up.
-        """
-        mode = pod_mode(problem)
-        if mode == "edge_slab":
-            problem, _ = slab_pad_problem(problem, self.plan.pod)
-        specs = problem_specs(problem, mode, batched=False)
-        return jax.device_put(problem, sharding_for(self.plan.build(), specs))
+        # (problem as given, launch key, padded + placed problem, ncols)
+        # of the last launch: the bound search's launches share one
+        # problem, which is padded and copied to the mesh only once
+        self._placed = None
 
     # -- feasibility primitives (everything else is inherited) ---------
     def _prepare_launch(self, problem, bounds, batched_problem: bool) -> dict:
@@ -158,18 +144,17 @@ class DistSolver(Solver):
         bounds = jnp.atleast_1d(jnp.asarray(bounds))
         B = int(bounds.shape[0])
         mode = pod_mode(problem)
-
-        if mode == "edge_slab":
-            problem, ncols = slab_pad_problem(problem, plan.pod)
-            _, block = partition_edges_1d(ncols, plan.pod)
-        else:
-            ncols = global_columns(problem, np.asarray(bounds)[0], batched_problem)
-            block = -(-ncols // plan.pod)
-
         pad = (-B) % plan.data
-        if pad:
-            bounds = jnp.concatenate([bounds, jnp.broadcast_to(bounds[-1:], (pad,))])
-            if batched_problem:
+        placed = self._placed
+        if placed is not None and placed[0] is problem and placed[1] == (batched_problem, pad):
+            problem, ncols = placed[2], placed[3]
+        else:
+            given = problem
+            if mode == "edge_slab":
+                problem, ncols = slab_pad_problem(problem, plan.pod)
+            else:
+                ncols = global_columns(problem, np.asarray(bounds)[0], batched_problem)
+            if pad and batched_problem:
                 problem = jax.tree.map(
                     lambda a: jnp.concatenate(
                         [
@@ -181,12 +166,22 @@ class DistSolver(Solver):
                     ),
                     problem,
                 )
+            if plan.n_devices > 1:
+                # each leaf goes where the launch reads it: in edge_slab
+                # mode every device holds only its own edge slab
+                specs = problem_specs(problem, mode, batched_problem)
+                problem = jax.device_put(problem, sharding_for(plan.build(), specs))
+            self._placed = (given, (batched_problem, pad), problem, ncols)
+        if mode == "edge_slab":
+            _, block = partition_edges_1d(ncols, plan.pod)
+        else:
+            block = -(-ncols // plan.pod)
+        if pad:
+            bounds = jnp.concatenate([bounds, jnp.broadcast_to(bounds[-1:], (pad,))])
         no_vmap = plan.n_devices > 1 and B + pad == plan.data
 
         kernels = _kd.resolve(self.opts.kernel_backend)  # host-side, pre-jit
         specs = problem_specs(problem, mode, batched_problem)
-        if plan.n_devices > 1:
-            problem = jax.device_put(problem, sharding_for(plan.build(), specs))
         key = (
             plan,
             self.opts,

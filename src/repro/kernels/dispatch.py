@@ -23,8 +23,11 @@ vector op" is decided. Three layers cooperate:
    VMEM ref, which Mosaic cannot lower: "Only 2D gather is supported"),
    gathers whose vertex vector exceeds :data:`VMEM_VERTEX_LIMIT`,
    float64 on a real TPU (no f64 VPU; interpret mode keeps f64 for CPU
-   CI parity), or masked reductions (the mask-aware paths stay on XLA —
-   handled at the call sites). Every decision is counted in
+   CI parity), line-search probes below f64 (the kernel returns lse,
+   and Psi/Phi as a difference of two lse cancel in f32; the XLA probe
+   takes them as ``log1p`` of a sum that does not), or masked
+   reductions (the mask-aware paths stay on XLA — handled at the call
+   sites). Every decision is counted in
    :func:`stats`, each gated fallback with its reason, so tests and
    ``benchmarks/bench_breakdown.py`` can prove the pallas path is
    active rather than silently falling back.
@@ -178,6 +181,7 @@ _STATS: dict[str, dict] = {}
 GATHER_NO_TPU_LOWERING = "gather: 1-D VMEM gather has no Mosaic lowering"
 F64_ON_TPU = "f64: no f64 vector unit on TPU"
 OVER_VMEM = "gather: vertex vector over the VMEM limit"
+PROBE_CANCELS = "probe: a difference of two lse cancels below f64"
 
 
 def _note(op: str, impl: str, reason: str | None = None) -> None:
@@ -210,6 +214,8 @@ def _gate(op: str, policy: KernelPolicy, n: int, dtype) -> tuple[str, str | None
     dt = jnp.dtype(dtype)
     if jnp.issubdtype(dt, jnp.floating) and dt.itemsize >= 8 and not policy.interpret:
         return "xla", F64_ON_TPU
+    if op == "probe" and dt.itemsize < 8:
+        return "xla", PROBE_CANCELS
     if op == "gather" and not policy.interpret:
         return "xla", GATHER_NO_TPU_LOWERING
     if op == "gather" and n > vmem_vertex_limit(dtype):

@@ -336,8 +336,9 @@ def test_lpserve_mesh_sharded_lanes():
 
 
 def test_place_puts_one_edge_slab_per_device():
-    """DistSolver.place leaves each device only its own edge slab, and a
-    solve of the placed problem equals one of the problem as built."""
+    """A pod=4 solve leaves each device only its own edge slab, places the
+    problem once for the whole bound search, and returns x and the
+    objective over the original edges."""
     out = run_sub(
         """
         import json
@@ -349,22 +350,26 @@ def test_place_puts_one_edge_slab_per_device():
 
         prob = matching_lp(erdos(60, 201, seed=1))
         solver = DistSolver(MWUOptions(), plan=MeshPlan(pod=4, data=1))
-        placed = solver.place(prob)
-        ref = solver.solve(prob)
-        sol = solver.solve(placed)
+        sol = solver.solve(prob)
+        given, _, placed, ncols = solver._placed
+        u = placed.P.u
+        again = solver._prepare_launch(prob, [1.0, 2.0], False)["problem"]
+        x = np.asarray(sol.x)
         print(json.dumps({
-            "u_shards": sorted(s.data.shape[0] for s in placed.P.u.addressable_shards),
-            "u_devices": len({s.device for s in placed.P.u.addressable_shards}),
-            "n_vars": int(placed.n_vars),
-            "objective": [ref.objective, sol.objective], "x_len": len(sol.x),
+            "given": given is prob, "reused": again is placed, "ncols": ncols,
+            "u_shards": sorted(s.data.shape[0] for s in u.addressable_shards),
+            "u_devices": len({s.device for s in u.addressable_shards}),
+            "calls": sol.feasibility_calls, "x_len": len(x),
+            "objective": [sol.objective, float(np.asarray(prob.c) @ x)],
         }))
         """,
         devices=4,
     )
     d = json.loads(out.strip().splitlines()[-1])
     assert d["u_shards"] == [51] * 4 and d["u_devices"] == 4  # 201 edges padded to 204
-    assert d["n_vars"] == 201 and d["x_len"] == 201
-    assert d["objective"][0] == d["objective"][1]
+    assert d["given"] and d["reused"] and d["calls"] > 1
+    assert d["ncols"] == 201 and d["x_len"] == 201
+    assert d["objective"][0] == pytest.approx(d["objective"][1], rel=1e-6)
 
 
 def test_pallas_pack_active_under_shard_map():

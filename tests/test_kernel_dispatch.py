@@ -321,6 +321,39 @@ def test_probe_fn_f32_matches_f64(alpha):
         )
 
 
+@pytest.mark.parametrize("interpret", [True, False])
+def test_pallas_probe_f32_matches_f64(interpret):
+    """Under a pallas policy an f32 probe takes the XLA path, whose Psi/Phi
+    do not cancel, and dispatch.stats() says why; f64 keeps the kernel in
+    interpret mode. The state is test_probe_fn_f32_matches_f64's at a = 1,
+    where the kernel's difference of two lse is 3% off in f32."""
+    rng = np.random.default_rng(3)
+    m = 4096
+    state = [rng.uniform(0.5, 1.0, m), rng.uniform(0.2, 0.9, m)]
+    state += [rng.uniform(0.0, 1e-6, m), rng.uniform(0.0, 1e-6, m)]
+    eta = 10 * np.log(2 * m) / 0.1
+
+    def probe(dt):
+        return make_probe_fn(*(jnp.asarray(a, dt) for a in state), jnp.asarray(eta, dt))(
+            jnp.asarray(1.0, dt)
+        )
+
+    ref = probe(jnp.float64)
+    kd.reset_stats()
+    with kd.use_policy(kd.KernelPolicy("pallas", interpret=interpret)):
+        got = probe(jnp.float32)
+        f32_stats = kd.stats()["probe"]
+        kd.reset_stats()
+        probe(jnp.float64)
+        f64_stats = kd.stats()["probe"]
+    assert f32_stats == {"pallas": 0, "xla": 1, "fallback": {kd.PROBE_CANCELS: 1}}
+    assert f64_stats["pallas"] == (1 if interpret else 0)
+    for name in ("psi", "phi", "f"):
+        np.testing.assert_allclose(
+            float(getattr(got, name)), float(getattr(ref, name)), rtol=1e-3, err_msg=name
+        )
+
+
 # -- 4. end to end ----------------------------------------------------------
 FAMILIES = ["match", "vcover", "dom-set", "dense-sub"]
 
