@@ -1,0 +1,24 @@
+"""The incidence scatter's share of its HBM roofline.
+
+Minimum bytes of every scatter-direction call (``opbytes``) over the device
+time of the ops that ``opnames/scatter.txt`` names, against the chip's HBM
+bandwidth. Both LPs scatter once per batched iteration; match also once
+when a launch starts (y = Px0, on the one x0 that all its lanes share).
+"""
+from perfbench import harness, opbytes, peaks
+
+
+def moved(run, x):
+    per_call = opbytes.incidence_bytes(x["n_vertices"], x["n_edges"], x["lanes"], x["index_sets"])
+    start = opbytes.incidence_bytes(x["n_vertices"], x["n_edges"], 1, x["index_sets"])
+    return x["batched_iters"] * per_call + (start if run.cell.config["lp"] == "match" else 0)
+
+
+def read(run):
+    if run.trace is None or not run.solves:
+        return None
+    seconds = run.trace.op_seconds(harness.opnames(run, "scatter"))
+    if seconds <= 0:
+        return None
+    total = sum(moved(run, x) for x in run.traced_launches)
+    return 100.0 * total / seconds / peaks.peaks(run.device_kind)["hbm_bytes_per_s"]
