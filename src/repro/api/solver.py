@@ -16,9 +16,20 @@ and searching the objective bound. Two execution modes:
 ``solve_batch`` exposes the raw fan-out: batched ``MWUResult`` across an
 array of bounds, optionally also across stacked same-shape graph
 instances (``stack_problems``).
+
+Each ``Solver.solve`` is a tree of host spans, written to the JAX
+profiler's trace as ``TraceAnnotation`` events tagged with the problem's
+name and the search round: ``solver.solve`` holds one ``solver.round`` per
+probe round and a closing ``solver.certify``; a round holds
+``solver.dispatch`` (the launch), ``solver.wait`` (the first host read of
+the launch's statuses, where the host blocks on the device) and
+``solver.readback`` (per-lane results and counts). Their self times, in
+host seconds, are ``Solution.timings``.
 """
 from __future__ import annotations
 
+import time
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 
@@ -51,6 +62,18 @@ class Solution:
     ``trace`` (optional) is a list of per-feasibility-call dicts from the
     io_callback trace hook, each with the probed ``bound`` plus the
     ``max_violation`` / ``alpha`` / ``probes`` arrays of Figure 3.
+
+    Counts of the search's launches: ``iter_limit_lanes`` lanes ended
+    ``ITER_LIMIT``; ``batched_iters`` is the sum over launches of their
+    slowest lane's iterations (a vmapped loop runs every lane that long)
+    and ``launched_lane_iters`` the sum of lanes x slowest lane, so
+    ``mwu_iters_total / launched_lane_iters`` is the share of launched
+    lane-iterations a lane needed. ``timings`` holds the host seconds of
+    ``Solver.solve`` by span (``dispatch``, ``wait``, ``readback``,
+    ``certify``, and ``search`` for the rest); they sum to the solve's
+    wall time. A solution of ``repro.lpserve``'s engine shares each launch
+    with other requests, so there the two launch counts and ``timings``
+    are None.
     """
 
     problem: str
@@ -65,6 +88,10 @@ class Solution:
     ls_probes_total: int
     last_result: MWUResult | None = None
     trace: list | None = None
+    iter_limit_lanes: int = 0
+    batched_iters: int | None = None
+    launched_lane_iters: int | None = None
+    timings: dict | None = None
 
     @property
     def found(self) -> bool:
@@ -140,6 +167,48 @@ def stack_problems(problems: list[Problem]) -> Problem:
         raise ValueError("stack_problems: need at least one problem")
     _check_stackable(list(problems))
     return jax.tree.map(lambda *ls: jnp.stack([jnp.asarray(l) for l in ls]), *problems)
+
+
+class _Spans:
+    """The host spans of one ``Solver.solve`` and their self times.
+
+    ``spans(name, key)`` is a ``solver.<name>`` profiler event tagged with
+    the problem and the current search round; on exit its duration less
+    that of the spans opened inside it adds to ``times[key]``.
+    """
+
+    def __init__(self, problem: str):
+        self.problem, self.round = problem, 0
+        self.times = dict.fromkeys(("dispatch", "wait", "readback", "certify", "search"), 0.0)
+        self._inner = [0.0]  # seconds in child spans, one entry per open span
+
+    @contextmanager
+    def __call__(self, name: str, key: str = "search"):
+        self._inner.append(0.0)
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(f"solver.{name}", problem=self.problem, round=self.round):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            self.times[key] += dt - self._inner.pop()
+            self._inner[-1] += dt
+
+
+def new_search_stats() -> dict:
+    """A search's counts; ``Solution`` takes them over (``_counts``)."""
+    return {"calls": 0, "iters": 0, "probes": 0, "iter_limit": 0, "batched_iters": 0, "launched_lane_iters": 0}
+
+
+def _count_launch(stats: dict, status: np.ndarray, iters: np.ndarray, probes: np.ndarray) -> None:
+    """Add one launch's lanes (host arrays, one entry per lane) to ``stats``."""
+    lanes, slowest = status.size, int(iters.max(initial=0))
+    stats["calls"] += lanes
+    stats["iters"] += int(iters.sum())
+    stats["probes"] += int(probes.sum())
+    stats["iter_limit"] += int(np.sum(status == Status.ITER_LIMIT))
+    stats["batched_iters"] += slowest
+    stats["launched_lane_iters"] += lanes * slowest
 
 
 class Solver:
@@ -239,60 +308,78 @@ class Solver:
     # -- the unified optimization driver ------------------------------
     def solve(self, problem: Problem, *, trace: bool = False) -> Solution:
         """Optimize ``problem`` via bound search over feasibility calls."""
-        if problem.bound_mode == "none":
-            return self._solve_feasibility(problem, trace)
-        return self._bound_search(problem, trace)
+        spans = _Spans(problem.name)
+        with spans("solve"):
+            if problem.bound_mode == "none":
+                sol = self._solve_feasibility(problem, trace, spans)
+            else:
+                sol = self._bound_search(problem, trace, spans)
+        sol.timings = spans.times
+        return sol
 
     # pure feasibility problems skip the search entirely
-    def _solve_feasibility(self, problem: Problem, trace: bool) -> Solution:
-        traces = None
-        if trace:
-            res, tr = self.feasible(problem, trace=True)
-            traces = [dict(bound=float("nan"), **tr)]
-        else:
-            res = self.feasible(problem)
-        stats = {"calls": 1, "iters": int(res.iters), "probes": int(res.ls_probes)}
-        return feasibility_solution(problem, res, stats, traces)
+    def _solve_feasibility(self, problem: Problem, trace: bool, spans: _Spans) -> Solution:
+        traces = [] if trace else None
+        stats = new_search_stats()
+        (_, res), = self._probe(problem, [None], trace, traces, stats, spans)
+        with spans("certify", "certify"):
+            return feasibility_solution(problem, res, stats, traces)
 
-    def _probe(self, problem, bounds, trace, traces, stats):
-        """Evaluate feasibility at each bound; batched when width allows."""
+    def _probe(self, problem, bounds, trace, traces, stats, spans):
+        """Evaluate feasibility at each bound: one search round.
+
+        Batched into one launch when the width allows, else one launch
+        per bound. The lanes' statuses, iterations and probes are read
+        back once per launch.
+        """
         outs = []
-        if len(bounds) > 1 and not trace:
-            batch = self.solve_batch(problem, jnp.asarray(bounds))
-            status = np.asarray(batch.status)
-            for j, b in enumerate(bounds):
-                lane = jax.tree.map(lambda a: a[j], batch)
-                outs.append((int(status[j]) == Status.FEASIBLE, lane))
-        else:
-            for b in bounds:
-                if trace:
-                    res, tr = self.feasible(problem, b, trace=True)
-                    traces.append(dict(bound=float(b), **tr))
-                else:
-                    res = self.feasible(problem, b)
-                outs.append((int(res.status) == Status.FEASIBLE, res))
-        stats["calls"] += len(bounds)
-        stats["iters"] += sum(int(r.iters) for _, r in outs)
-        stats["probes"] += sum(int(r.ls_probes) for _, r in outs)
+        with spans("round"):
+            if len(bounds) > 1 and not trace:
+                with spans("dispatch", "dispatch"):
+                    batch = self.solve_batch(problem, jnp.asarray(bounds))
+                with spans("wait", "wait"):
+                    status = np.asarray(batch.status)
+                with spans("readback", "readback"):
+                    for j, ok in enumerate(status == Status.FEASIBLE):
+                        outs.append((bool(ok), jax.tree.map(lambda a: a[j], batch)))
+                    _count_launch(stats, status, np.asarray(batch.iters), np.asarray(batch.ls_probes))
+            else:
+                for b in bounds:
+                    with spans("dispatch", "dispatch"):
+                        if trace:
+                            res, tr = self.feasible(problem, b, trace=True)
+                            traces.append(dict(bound=float("nan") if b is None else float(b), **tr))
+                        else:
+                            res = self.feasible(problem, b)
+                    with spans("wait", "wait"):
+                        status = np.atleast_1d(np.asarray(res.status))
+                    with spans("readback", "readback"):
+                        outs.append((int(status[0]) == Status.FEASIBLE, res))
+                        _count_launch(stats, status, np.atleast_1d(np.asarray(res.iters)),
+                                      np.asarray(res.ls_probes))
+        spans.round += 1
         return outs
 
-    def _bound_search(self, problem: Problem, trace: bool) -> Solution:
+    def _bound_search(self, problem: Problem, trace: bool, spans: _Spans) -> Solution:
         is_max = problem.feasible_side == "lo"
         lo, hi = float(problem.lo), float(problem.hi)
         rel = self.rel_tol if self.rel_tol is not None else self.opts.eps / 2
         K = 1 if trace else self.batch_width
-        stats = {"calls": 0, "iters": 0, "probes": 0}
+        stats = new_search_stats()
         traces: list = [] if trace else None
         best = best_bound = None
+
+        def probe(bounds):
+            return self._probe(problem, bounds, trace, traces, stats, spans)
 
         # min-like senses: the feasible side is hi; the legacy drivers
         # check it up front and bail immediately when even hi fails.
         # (With K > 1 the endpoint could ride along in round 1's batch,
         # but checking it alone first keeps the not-found exit cheap.)
         if not is_max:
-            (ok, res), = self._probe(problem, [hi], trace, traces, stats)
+            (ok, res), = probe([hi])
             if not ok:
-                return self._not_found(problem, hi, res, stats, traces)
+                return self._not_found(problem, hi, res, stats, traces, spans)
             best, best_bound = res, hi
 
         first = True
@@ -303,7 +390,7 @@ class Solver:
                 pts = [lo * r ** (k / K) for k in range(K)]
             else:
                 pts = [lo * r ** (k / (K + 1)) for k in range(1, K + 1)]
-            outs = self._probe(problem, pts, trace, traces, stats)
+            outs = probe(pts)
             feas = [ok for ok, _ in outs]
             if is_max:
                 # feasible for small bounds: push lo up to the largest
@@ -314,7 +401,7 @@ class Solver:
                     lo, best, best_bound = pts[j], outs[j][1], pts[j]
                 else:
                     if first and K > 1:  # round 1 included lo itself
-                        return self._not_found(problem, lo, outs[0][1], stats, traces)
+                        return self._not_found(problem, lo, outs[0][1], stats, traces, spans)
                 i_idx = [i for i, ok in enumerate(feas) if not ok]
                 if i_idx:
                     hi = pts[i_idx[0]]
@@ -330,21 +417,35 @@ class Solver:
             first = False
 
         if best is None:  # only reachable for sense="max" (lo never probed)
-            (ok, res), = self._probe(problem, [lo], trace, traces, stats)
+            (ok, res), = probe([lo])
             if not ok:
-                return self._not_found(problem, lo, res, stats, traces)
+                return self._not_found(problem, lo, res, stats, traces, spans)
             best, best_bound = res, lo
 
-        return self._certify(problem, best, best_bound, stats, traces)
+        return self._certify(problem, best, best_bound, stats, traces, spans)
 
-    def _not_found(self, problem, bound, res, stats, traces) -> Solution:
-        return not_found_solution(problem, bound, res, stats, traces)
+    def _not_found(self, problem, bound, res, stats, traces, spans) -> Solution:
+        with spans("certify", "certify"):
+            return not_found_solution(problem, bound, res, stats, traces)
 
-    def _certify(self, problem, best, best_bound, stats, traces) -> Solution:
-        return certify_solution(problem, best, best_bound, stats, traces)
+    def _certify(self, problem, best, best_bound, stats, traces, spans) -> Solution:
+        with spans("certify", "certify"):
+            return certify_solution(problem, best, best_bound, stats, traces)
 
 
 # -- Solution construction (shared with repro.lpserve's engine) -----------
+def _counts(stats) -> dict:
+    """Solution's counts from a search's ``stats`` (``new_search_stats``)."""
+    return dict(
+        feasibility_calls=stats["calls"],
+        mwu_iters_total=stats["iters"],
+        ls_probes_total=stats["probes"],
+        iter_limit_lanes=stats["iter_limit"],
+        batched_iters=stats["batched_iters"],
+        launched_lane_iters=stats["launched_lane_iters"],
+    )
+
+
 def feasibility_solution(problem, res, stats, traces=None) -> Solution:
     """Solution for a single feasibility solve (``bound_mode="none"``)."""
     ok = int(res.status) == Status.FEASIBLE
@@ -356,9 +457,7 @@ def feasibility_solution(problem, res, stats, traces=None) -> Solution:
         bound=float("nan"),
         max_px=float(res.max_px),
         min_cx=float(res.min_cx),
-        feasibility_calls=stats["calls"],
-        mwu_iters_total=stats["iters"],
-        ls_probes_total=stats["probes"],
+        **_counts(stats),
         last_result=res,
         trace=traces,
     )
@@ -374,9 +473,7 @@ def not_found_solution(problem, bound, res, stats, traces=None) -> Solution:
         bound=float(bound),
         max_px=float(res.max_px),
         min_cx=float(res.min_cx),
-        feasibility_calls=stats["calls"],
-        mwu_iters_total=stats["iters"],
-        ls_probes_total=stats["probes"],
+        **_counts(stats),
         last_result=res,
         trace=traces,
     )
@@ -405,9 +502,7 @@ def certify_solution(problem, best, best_bound, stats, traces=None) -> Solution:
         bound=float(best_bound),
         max_px=float(best.max_px),
         min_cx=float(best.min_cx),
-        feasibility_calls=stats["calls"],
-        mwu_iters_total=stats["iters"],
-        ls_probes_total=stats["probes"],
+        **_counts(stats),
         last_result=best,
         trace=traces,
     )

@@ -194,7 +194,8 @@ def _iteration(P: LinOp, C: LinOp, eta, scale, step_fn, ls_eps, p_mask, c_mask, 
     dz = C.matvec(d)
 
     # step size (line 11)
-    ss: StepSizeResult = step_fn(y, z, dy, dz, eta, p_mask, c_mask, ls_eps, carry.alpha_prev)
+    with jax.named_scope("mwu.linesearch"):
+        ss: StepSizeResult = step_fn(y, z, dy, dz, eta, p_mask, c_mask, ls_eps, carry.alpha_prev)
     infeasible_alpha = ss.alpha < 1  # line 12
 
     # apply (lines 14-15); never move on a terminal iteration. Under a

@@ -219,12 +219,14 @@ class Incidence(LinOp):
             w = jnp.where(self.edge_mask, w, 0)
         return w
 
+    @jax.named_scope("incidence.scatter")
     def matvec(self, x):
         # y_u += x_e ; y_v += x_e  (scatter direction)
         xw = x * self._w(x.dtype)
         out = jnp.zeros((self.n_vertices,), dtype=x.dtype)
         return out.at[self.u].add(xw).at[self.v].add(xw)
 
+    @jax.named_scope("incidence.gather")
     def rmatvec(self, y):
         # g_e = y_u + y_v  (gather direction — the Pallas hot spot)
         if _kd.choose("gather", y) == "pallas":

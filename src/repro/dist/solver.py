@@ -113,18 +113,21 @@ class DistSolver(Solver):
     ``dist_stats`` counts launches / lanes / MWU iterations and (for
     pod-sharded plans) an estimate of psum rounds — 3 collectives per
     iteration (dy, dz, pmax) plus init (y, z, pmin) — surfaced by
-    ``repro.lpserve``'s ``stats()``.
+    ``repro.lpserve``'s ``stats()``. A launch is counted at the next
+    launch or when ``dist_stats`` is read, so ``solve_batch`` never waits
+    on the device.
     """
 
     def __init__(self, opts=None, *, plan: MeshPlan | None = None, **kwargs):
         super().__init__(opts, **kwargs)
         self.plan = plan if plan is not None else MeshPlan()
-        self.dist_stats = {
+        self._dist_stats = {
             "launches": 0,
             "feasibility_calls": 0,
             "mwu_iters": 0,
             "psum_rounds": 0,
         }
+        self._uncounted = None  # the last launch's iterations, on the device
         # (problem as given, launch key, padded + placed problem, ncols)
         # of the last launch: the bound search's launches share one
         # problem, which is padded and copied to the mesh only once
@@ -231,19 +234,33 @@ class DistSolver(Solver):
         """
         launch = self._prepare_launch(problem, bounds, batched_problem)
         problem, bounds, fn = launch["problem"], launch["bounds"], launch["fn"]
-        plan, B, ncols = self.plan, launch["B"], launch["ncols"]
+        B, ncols = launch["B"], launch["ncols"]
 
         res = fn(problem, bounds)
         res = jax.tree.map(lambda a: a[:B], res)
         res = res._replace(x=res.x[:, :ncols])
 
-        iters = np.asarray(res.iters)
-        self.dist_stats["launches"] += 1
-        self.dist_stats["feasibility_calls"] += B
-        self.dist_stats["mwu_iters"] += int(iters.sum())
-        if plan.pod > 1:
-            self.dist_stats["psum_rounds"] += 3 * int(iters.max(initial=0)) + 3
+        self._count_launch()
+        self._uncounted = res.iters
         return res
+
+    def _count_launch(self) -> None:
+        """Add the last launch to ``dist_stats``; by the next launch its
+        caller has read it, so this does not wait."""
+        if self._uncounted is None:
+            return
+        iters, self._uncounted = np.asarray(self._uncounted), None
+        st = self._dist_stats
+        st["launches"] += 1
+        st["feasibility_calls"] += iters.size
+        st["mwu_iters"] += int(iters.sum())
+        if self.plan.pod > 1:
+            st["psum_rounds"] += 3 * int(iters.max(initial=0)) + 3
+
+    @property
+    def dist_stats(self) -> dict:
+        self._count_launch()
+        return self._dist_stats
 
     def feasible(self, problem, bound=None, trace: bool = False):
         """One feasibility solve, pod-sharded when the plan is multi-device.

@@ -39,6 +39,7 @@ from ..api.solver import (
     Solver,
     certify_solution,
     feasibility_solution,
+    new_search_stats,
     not_found_solution,
     stack_problems,
 )
@@ -94,7 +95,8 @@ class BoundSearch:
         self.problem = problem
         self.rel = rel_tol
         self.max_calls = max_calls
-        self.stats = {"calls": 0, "iters": 0, "probes": 0}
+        # launches are shared with other requests: no launch counts
+        self.stats = {**new_search_stats(), "batched_iters": None, "launched_lane_iters": None}
         self.best: MWUResult | None = None
         self.best_bound: float | None = None
         self.solution: Solution | None = None
@@ -135,11 +137,13 @@ class BoundSearch:
 
     def update(self, bound: float, res: MWUResult) -> None:
         assert not self.done, "search already finished"
-        ok = int(res.status) == Status.FEASIBLE
+        status = int(res.status)
+        ok = status == Status.FEASIBLE
         st = self.stats
         st["calls"] += 1
         st["iters"] += int(res.iters)
         st["probes"] += int(res.ls_probes)
+        st["iter_limit"] += int(status == Status.ITER_LIMIT)
 
         if self.phase == "single":
             self.solution = feasibility_solution(self.problem, res, st)

@@ -29,6 +29,11 @@ The HLO text parser itself lives in :mod:`repro.tracecheck.hlo_ir`,
 shared with the static-analysis gate so the roofline and the linter
 read one IR. Validated against closed-form expectations in
 tests/test_hlo_analyzer.py.
+
+:func:`op_scopes` reads the same text for the ``jax.named_scope`` each
+instruction came from, so a profiler trace's device ops can be summed by
+scope (the solver's are ``incidence.scatter``, ``incidence.gather`` and
+``mwu.linesearch``).
 """
 from __future__ import annotations
 
@@ -45,8 +50,9 @@ from ..tracecheck.hlo_ir import (
     trip_count,
 )
 
-__all__ = ["analyze_hlo", "HloReport"]
+__all__ = ["analyze_hlo", "HloReport", "op_scopes"]
 
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all", "collective-permute")
 _SKIP_BYTES = {
     "tuple", "get-tuple-element", "parameter", "constant", "bitcast", "iota",
@@ -320,3 +326,23 @@ def analyze_hlo(text, num_partitions: int = 1, *, root: str | None = None) -> Hl
         for k, v in top
     ]
     return rep
+
+
+def op_scopes(text: str, scopes) -> dict[str, str]:
+    """Instruction name -> the innermost of ``scopes`` in its ``op_name``.
+
+    ``text`` is a compiled program (``compiled.as_text()``) and ``scopes``
+    names of ``jax.named_scope``. A profiler trace names each device op by
+    its instruction in the compiled program (``%fusion.118 = ...``), and a
+    fusion keeps the metadata of its root, so this map sorts a trace's
+    device time by scope. Instructions under none of ``scopes`` are left
+    out. A transform can wrap a scope in the name (``vmap(incidence.scatter)``).
+    """
+    out = {}
+    for comp in parse_hlo(text).comps.values():
+        for op in comp.ops:
+            m = _OP_NAME_RE.search(op.rest)
+            inner = [part for part in re.split(r"[/()]", m.group(1)) if part in scopes] if m else []
+            if inner:
+                out[op.name] = inner[-1]
+    return out
