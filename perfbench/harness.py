@@ -9,7 +9,10 @@ a file of its own, found by the name that ``BENCHMARK.json`` gives it:
   with ``setup``, ``window(seconds)``, ``record``, ``free``, ``check``
   and a ``counter``, its ``instrument.LaunchCounter``);
 * ``metrics/<metric>.py``: one reader per metric, ``read(run) -> float | None``;
-* ``opnames/<op>.txt``: the trace names that count as one operation.
+  a reader of device time finds an operation by the ``jax.named_scope`` the
+  program gives it (``trace.Summary.scope_seconds``), not by XLA's op names;
+* ``opnames/<op>.txt``: the HLO text of one operation's ops, read only for
+  ops that a trace puts in no program, and so has no scopes for.
 
 A new cell, metric or configuration is new files and new entries; no file
 here changes.

@@ -1,10 +1,16 @@
 """The incidence gather's share of its HBM roofline.
 
-Minimum bytes of every gather-direction call (``opbytes``) over the device
-time of the ops that ``opnames/gather.txt`` names, against the chip's HBM
-bandwidth. Both LPs gather once per batched iteration. (Vertex cover's
-z = Cx0 at a launch's start reads the uniform x0, and XLA does not emit a
-gather for it: a v5e trace shows one gather fusion per iteration.)
+Minimum bytes of every gather-direction call (``opbytes``, from the shapes
+alone) over the device time of the ops that their own program's compiled
+HLO puts under the ``incidence.gather`` scope (``Summary.scope_seconds``),
+against the chip's HBM bandwidth. So it reads the same work whatever code
+does the gather. A fusion takes the scope of its root: work fused into a
+consumer outside the scope counts there. An op that the trace puts in
+no program, and so has no scopes for, is matched by its HLO text against
+``opnames/gather.txt``. Both LPs gather once per batched
+iteration. (Vertex cover's z = Cx0 at a launch's start reads the uniform
+x0, and XLA does not emit a gather for it: a v5e trace shows one gather
+fusion per iteration.)
 """
 from perfbench import harness, opbytes, peaks
 
@@ -17,8 +23,8 @@ def moved(x):
 def read(run):
     if run.trace is None or not run.solves:
         return None
-    seconds = run.trace.op_seconds(harness.opnames(run, "gather"))
-    if seconds <= 0:
+    seconds = run.trace.scope_seconds("incidence.gather", harness.opnames(run, "gather"))
+    if not seconds:
         return None
     total = sum(moved(x) for x in run.traced_launches)
     return 100.0 * total / seconds / peaks.peaks(run.device_kind)["hbm_bytes_per_s"]

@@ -1,9 +1,15 @@
 """The incidence scatter's share of its HBM roofline.
 
-Minimum bytes of every scatter-direction call (``opbytes``) over the device
-time of the ops that ``opnames/scatter.txt`` names, against the chip's HBM
-bandwidth. Both LPs scatter once per batched iteration; match also once
-when a launch starts (y = Px0, on the one x0 that all its lanes share).
+Minimum bytes of every scatter-direction call (``opbytes``, from the shapes
+alone) over the device time of the ops that their own program's compiled
+HLO puts under the ``incidence.scatter`` scope (``Summary.scope_seconds``),
+against the chip's HBM bandwidth. So it reads the same work whatever code
+does the scatter. A fusion takes the scope of its root: work fused into a
+consumer outside the scope counts there. An op that the trace puts in
+no program, and so has no scopes for, is matched by its HLO text against
+``opnames/scatter.txt``. Both LPs scatter once per batched
+iteration; match also once when a launch starts (y = Px0, on the one x0
+that all its lanes share).
 """
 from perfbench import harness, opbytes, peaks
 
@@ -17,8 +23,8 @@ def moved(run, x):
 def read(run):
     if run.trace is None or not run.solves:
         return None
-    seconds = run.trace.op_seconds(harness.opnames(run, "scatter"))
-    if seconds <= 0:
+    seconds = run.trace.scope_seconds("incidence.scatter", harness.opnames(run, "scatter"))
+    if not seconds:
         return None
     total = sum(moved(run, x) for x in run.traced_launches)
     return 100.0 * total / seconds / peaks.peaks(run.device_kind)["hbm_bytes_per_s"]

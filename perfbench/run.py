@@ -8,9 +8,10 @@ program the window runs) is timed from the start of this script; then the
 cell's driver measures for ``--seconds`` seconds; then every answer the
 window produced is checked against the plain reference. With ``--trace 1``
 the JAX profiler records the window's launches up to the first that starts
-``TRACE_SECONDS`` into it, and the per-layer metrics are read from that
-trace and from the whole window's counters; with ``--trace 0`` the
-end-to-end metrics.
+``TRACE_SECONDS`` into it, with each program's HLO, and the per-layer
+metrics are read from that trace (device ops by the named scope their own
+program gives them) and from the whole window's counters; with
+``--trace 0`` the end-to-end metrics.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (with ``busy_s`` and
@@ -118,7 +119,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, rehearse: bool = Fal
         shutil.rmtree(trace_dir, ignore_errors=True)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0  # the bench.* spans are host TraceMe events
-        options.enable_hlo_proto = False
+        options.enable_hlo_proto = True  # each program's HLO, to look its ops' scopes up in
         jax.profiler.start_trace(str(trace_dir), profiler_options=options)
         span = jax.profiler.TraceAnnotation(WINDOW_SPAN)  # made after the start, or it records nothing
         span.__enter__()
@@ -144,6 +145,12 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, rehearse: bool = Fal
         shutil.rmtree(trace_dir, ignore_errors=True)
         if run.trace is not None:
             device.update(busy_s=run.trace.busy_s, window_s=run.trace.window_s)
+            if run.trace.unmapped:
+                print(f"perfbench: no HLO in the trace for {', '.join(run.trace.unmapped)}: "
+                      "no op is read by scope", file=sys.stderr)
+            if run.trace.unplaced_s:
+                print(f"perfbench: {run.trace.unplaced_s} s of device ops ran in no program: "
+                      "read by opnames/", file=sys.stderr)
     result["metrics"] = harness.read_metrics(run, cell.per_layer if traced else cell.end_to_end)
     result["device"] = device
     if traced and run.trace is not None:

@@ -4,13 +4,12 @@ from __future__ import annotations
 import json
 import re
 import shutil
-from pathlib import Path
 
 import numpy as np
 import pytest
 
 from perfbench import graph500, harness, lp, opbytes, peaks, reference, trace
-from perfbench.instrument import LaunchCounter
+from perfbench.instrument import LaunchCounter, incidence_dims
 
 ROOT = harness.ROOT
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -151,23 +150,42 @@ def test_a_run_without_a_tpu_exits_nonzero(capsys):
 
 
 # -- the trace reduction -----------------------------------------------------
+def hlo_module(program: str, ops: dict[str, str | None]) -> str:
+    """A compiled program's HLO text as the profile's HLO proto prints it:
+    one instruction per op label (an instruction's HLO text, as a trace
+    names it), under the named scope given (None: under none of the solver's)."""
+    lines = [f"HloModule {program.split('(')[0]}, entry_computation_layout={{()->f32[]}}", "",
+             "ENTRY %main.1 () -> f32[] {"]
+    for label, scope in ops.items():
+        path = f"jit(_feasibility_batch)/vmap(while)/body/vmap({scope})/op" if scope else "jit(_feasibility_batch)/add"
+        name = label.split(" ")[0]
+        text = label if " = " in label else f"%{name} = f32[4]{{0}} {name.split('.')[0]}(f32[4]{{0}} %p)"
+        lines.append(f'  {text}, metadata={{op_name="{path}" source_file="x.py"}}')
+    return "\n".join(lines + ["}"])
+
+
+SOLVE = "jit_solve(1)"
+
+
 def synthetic_trace() -> trace.Trace:
     iv = trace.Interval
     return trace.Trace(
         devices={
             "/device:TPU:0": [
-                iv("scatter.1 hlo_module=jit_solve", 100, 300),
-                iv("gather.2 hlo_module=jit_solve", 250, 400),
-                iv("fusion.3 hlo_module=jit_solve", 600, 700),
-                iv("fusion.3 hlo_module=jit_solve", 1100, 1200),  # after the window
+                iv("scatter.1 hlo_module=jit_solve", 100, 300, SOLVE),
+                iv("gather.2 hlo_module=jit_solve", 250, 400, SOLVE),
+                iv("fusion.3 hlo_module=jit_solve", 600, 700, SOLVE),
+                iv("fusion.3 hlo_module=jit_solve", 1100, 1200, SOLVE),  # after the window
             ],
-            "/device:TPU:1": [iv("all-reduce.4", 0, 1000)],
+            "/device:TPU:1": [iv("all-reduce.4", 0, 1000, SOLVE)],
         },
         spans=[
             iv(trace.WINDOW_SPAN, 0, 1000),
             iv("bench.solve", 0, 800),
             iv("bench.launch", 40, 450),
         ],
+        programs={SOLVE: hlo_module(SOLVE, {"scatter.1": "incidence.scatter", "gather.2": "incidence.gather",
+                                            "fusion.3": None, "all-reduce.4": None})},
     )
 
 
@@ -179,15 +197,95 @@ def test_trace_reduction_gives_known_times():
     assert s.busy_s == pytest.approx((400 + 1000) / 2 * 1e-9)
     assert s.idle_share == pytest.approx(1 - 700 / 1000)
     # op time is summed per device then averaged over the two devices
-    assert s.op_seconds([re.compile(r"\bscatter")]) == pytest.approx(200e-9 / 2)
-    assert s.op_seconds([re.compile(r"\bgather")]) == pytest.approx(150e-9 / 2)
-    assert s.op_seconds([re.compile("all-reduce")]) == pytest.approx(1000e-9 / 2)
+    assert s.scope_seconds("incidence.scatter") == pytest.approx(200e-9 / 2)
+    assert s.scope_seconds("incidence.gather") == pytest.approx(150e-9 / 2)
+    assert s.op_s[SOLVE, "all-reduce.4"] == pytest.approx(1000e-9 / 2)
+    assert s.unmapped == []
     # device 0's gaps: 0..100 in the launch, 400..600 in the solve, 700..1000 outside
     assert s.idle_by_span_s == pytest.approx(
         {"bench.launch": 100e-9 / 2, "bench.solve": 200e-9 / 2, "outside": 300e-9 / 2})
     b = s.breakdown()
     assert b["device_ops"][0] == ["all-reduce.4", pytest.approx(500e-9)]
     assert b["idle_gaps"][0] == ["outside", pytest.approx(150e-9)]
+
+
+def test_two_programs_that_reuse_instruction_names_keep_their_own_scopes():
+    """Two graphs of a pool compile to programs that differ in shapes alone and
+    reuse instruction names; each op's time lands on its own program's scope."""
+    iv, a, b = trace.Interval, "jit__feasibility_batch(11)", "jit__feasibility_batch(22)"
+    t = trace.Trace(
+        devices={"/device:TPU:0": [iv("%fusion.118 = f32[4,64]", 100, 300, a),
+                                   iv("%fusion.118 = f32[4,64]", 400, 450, b),
+                                   iv("%fusion.7 = f32[4,90]", 500, 530, a), iv("%fusion.7 = f32[4,80]", 600, 700, b)]},
+        spans=[iv(trace.WINDOW_SPAN, 0, 1000)],
+        programs={a: hlo_module(a, {"fusion.118": "incidence.scatter", "fusion.7": "incidence.gather"}),
+                  b: hlo_module(b, {"fusion.118": "incidence.gather", "fusion.7": "incidence.scatter"})},
+    )
+    s = trace.summarize(t)
+    assert s.scope_seconds("incidence.scatter") == pytest.approx((200 + 100) * 1e-9)
+    assert s.scope_seconds("incidence.gather") == pytest.approx((50 + 30) * 1e-9)
+    # the breakdown still names ops as the trace does, summed over programs
+    assert s.breakdown()["device_ops"][0] == ["%fusion.118", pytest.approx(250e-9)]
+
+
+def _roofline_run(summary, lp_kind="match") -> harness.RunRecord:
+    cell = harness.resolve({"match": "g500-match.s16", "vcover": "g500-vcover.s13"}[lp_kind])
+    run = harness.RunRecord(cell, "TPU v5 lite", 1)
+    run.launches = [{"lanes": 4, "n_vertices": 64, "n_edges": 500, "index_sets": 1, "batched_iters": 10,
+                     "lane_iters": 40, "bounds": [1.0] * 4, "feasible": [True] * 4}]
+    run.solves = [{"launches": run.launches}]
+    run.traced, run.trace = 1, summary
+    return run
+
+
+def test_no_op_under_a_scope_reads_none_not_zero():
+    iv = trace.Interval
+    t = trace.Trace(devices={"/device:TPU:0": [iv("%fusion.1 = f32[4,64]", 100, 300, SOLVE)]},
+                    spans=[iv(trace.WINDOW_SPAN, 0, 1000)],
+                    programs={SOLVE: hlo_module(SOLVE, {"fusion.1": "incidence.scatter"})})
+    s = trace.summarize(t)
+    assert s.scope_seconds("incidence.gather") is None
+    metrics = [m for m in BENCH["per_layer"] if m["name"].endswith("_roofline")]
+    got = harness.read_metrics(_roofline_run(s), metrics)
+    assert set(got) == {"scatter_roofline"} and got["scatter_roofline"]["value"] > 0
+
+
+def test_a_program_without_its_hlo_is_named_and_reads_no_scope():
+    """Ops of a program whose HLO the trace lacks: no roofline is read from
+    another program's scopes, and the program is named."""
+    iv, other = trace.Interval, "jit__feasibility_batch(99)"
+    t = trace.Trace(devices={"/device:TPU:0": [iv("%fusion.1 = f32[4,64]", 100, 300, SOLVE),
+                                               iv("%fusion.1 = f32[4,64]", 400, 500, other),
+                                               iv("%copy.2 = f32[4]", 600, 610)]},
+                    spans=[iv(trace.WINDOW_SPAN, 0, 1000)],
+                    programs={SOLVE: hlo_module(SOLVE, {"fusion.1": "incidence.scatter"})})
+    s = trace.summarize(t)
+    assert s.unmapped == [other] and s.unplaced_s == pytest.approx(10e-9)
+    assert s.scope_seconds("incidence.scatter") is None
+    metrics = [m for m in BENCH["per_layer"] if m["name"].endswith("_roofline")]
+    assert harness.read_metrics(_roofline_run(s), metrics) == {}
+
+
+class _Event:
+    def __init__(self, name, start, end):
+        self.name, self.start_ns, self.duration_ns, self.stats = name, start, end - start, []
+
+
+class _Line:
+    def __init__(self, name, events):
+        self.name, self.events = name, [_Event(*e) for e in events]
+
+
+def test_device_ops_take_the_program_whose_run_holds_them():
+    plane = type("Plane", (), {"lines": [
+        _Line(trace.OPS_LINE, [("%fusion.1 = f32[4]", 110, 150), ("%while.2 = (f32[4]) while((f32[4]) %t)", 150, 390),
+                               ("%fusion.1 = f32[4]", 520, 560), ("%copy.3 = f32[4]", 700, 710)]),
+        _Line(trace.MODULES_LINE, [("jit_f(2)", 500, 600), ("jit_f(1)", 100, 400)]),
+    ]})()
+    ops = trace._device_ops(plane)
+    assert [(iv.label, iv.program) for iv in ops] == [
+        ("%fusion.1 = f32[4]", "jit_f(1)"), ("%fusion.1 = f32[4]", "jit_f(2)"), ("%copy.3 = f32[4]", trace.NO_PROGRAM)]
+    assert trace.CONTAINER.search("%while.244 = (f32[4,910200]) while((f32[4,910200]) %tuple.173), condition=%c")
 
 
 def test_trace_without_a_window_reads_nothing():
@@ -213,6 +311,29 @@ def test_trace_load_reads_a_recorded_profile(tmp_path):
     assert trace.WINDOW_SPAN in labels and "bench.solve" in labels
     assert t.devices == {}  # a CPU run has no TPU plane, so nothing is busy
     assert trace.summarize(t) is None
+
+
+def test_trace_load_reads_each_programs_hlo_from_a_recorded_profile(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def f(x):
+        with jax.named_scope("incidence.scatter"):
+            y = jnp.zeros(8).at[jnp.arange(256) % 8].add(x)
+        return y * 2
+
+    x = jnp.ones(256)
+    f(x).block_until_ready()
+    options = jax.profiler.ProfileOptions()
+    options.enable_hlo_proto = True
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    f(x).block_until_ready()
+    jax.profiler.stop_trace()
+    programs = trace.load(tmp_path).programs
+    names = [p for p in programs if p.startswith("jit_f(")]
+    assert len(names) == 1 and names[0].endswith(")")
+    assert set(trace.op_scopes(programs[names[0]], ("incidence.scatter",)).values()) == {"incidence.scatter"}
 
 
 def test_op_name_lists_parse():
@@ -250,6 +371,110 @@ def test_op_name_lists_pick_the_operators_out_of_a_v5e_trace(op):
     for label, which in V5E_MATCH_OPS.items():
         assert any(p.search(label) for p in patterns) == (which == op), label[:40]
     assert trace.CONTAINER.search("%while.244 = (f32[4,910200]) while((f32[4,910200]) %tuple.173), condition=%c")
+
+
+@pytest.mark.parametrize("op", ["scatter", "gather"])
+def test_ops_in_no_program_are_read_by_their_op_names(op):
+    """Ops that ran outside every program's run have no HLO to look a scope
+    up in: they count by ``opnames/``, beside the ops read by scope."""
+    program = "jit__feasibility_batch(7)"
+    ms = {label: (i + 1) * 1e-3 for i, label in enumerate(V5E_MATCH_OPS)}
+    op_s = {(trace.NO_PROGRAM, label): t for label, t in ms.items()}
+    op_s[program, "%fusion.9 = f32[4,65536]"] = 0.5
+    s = trace.Summary(window_s=1.0, busy_s=sum(op_s.values()), n_devices=1, op_s=op_s, idle_by_span_s={},
+                      programs={program: hlo_module(program, {"fusion.9": f"incidence.{op}"})})
+    run = _roofline_run(s)
+    run.launches[:] = [dict(run.launches[0], n_vertices=65536, n_edges=910200)]
+    assert s.unmapped == [] and s.unplaced_s == pytest.approx(sum(ms.values()))
+    want = 0.5 + sum(t for label, t in ms.items() if V5E_MATCH_OPS[label] == op)
+    assert s.scope_seconds(f"incidence.{op}", harness.opnames(run, op)) == pytest.approx(want)
+    assert s.scope_seconds(f"incidence.{op}") == pytest.approx(0.5)
+    metric = [m for m in BENCH["per_layer"] if m["name"] == f"{op}_roofline"]
+    assert harness.read_metrics(run, metric)[f"{op}_roofline"]["value"] > 0
+
+
+@pytest.mark.parametrize("op", ["scatter", "gather"])
+def test_v5e_match_ops_land_on_their_scopes(op):
+    """The op labels of a v5e trace, looked up in the scope map of a program
+    that holds them, land on the operator each belongs to."""
+    program = "jit__feasibility_batch(4427881338085217842)"
+    text = hlo_module(program, {label: which and f"incidence.{which}" for label, which in V5E_MATCH_OPS.items()})
+    ms = {label: (i + 1) * 1e-3 for i, label in enumerate(V5E_MATCH_OPS)}
+    s = trace.Summary(window_s=1.0, busy_s=sum(ms.values()), n_devices=1,
+                      op_s={(program, label): t for label, t in ms.items()}, idle_by_span_s={},
+                      programs={program: text})
+    want = sum(t for label, t in ms.items() if V5E_MATCH_OPS[label] == op)
+    assert s.scope_seconds(f"incidence.{op}") == pytest.approx(want)
+
+
+def _gather_reduce_matvec(self, x):
+    """The scatter direction with no scatter: each endpoint's edges summed
+    from a prefix sum in endpoint order, read back at the segment ends."""
+    import jax.numpy as jnp
+
+    xw = x * self._w(x.dtype)
+    ends = jnp.concatenate([self.u, self.v])
+    order = jnp.argsort(ends)
+    sums = jnp.concatenate([jnp.zeros(1, x.dtype), jnp.cumsum(jnp.concatenate([xw, xw])[order])])
+    cuts = jnp.searchsorted(ends[order], jnp.arange(self.n_vertices + 1))
+    return sums[cuts[1:]] - sums[cuts[:-1]]
+
+
+def _compiled_batch(family: str):
+    """The batched program of a tiny Graph500 LP as the CPU compiles it, and its launch's shapes."""
+    import jax
+
+    from repro.api import Solver
+    from repro.core.operators import Incidence
+
+    cfg = json.loads((ROOT / f"perfbench/configs/g500-{family}.json").read_text())
+    n, u, v = graph500.kron(6, 1)
+    p = lp.problem(cfg, n, u, v, "kron-6-1")
+    op = next(getattr(o, "inner", o) for o in (p.P, p.C) if isinstance(getattr(o, "inner", o), Incidence))
+    x = np.linspace(0.0, 1.0, len(u))
+    assert np.allclose(op.matvec(x), np.bincount(u, x, n) + np.bincount(v, x, n))
+    text = Solver(lp.options(cfg), batch_width=4).lower_batch(p, np.linspace(1.0, 2.0, 4)).compile().as_text()
+    jax.clear_caches()  # the next variant traces afresh
+    n_vertices, n_edges, index_sets = incidence_dims(p)
+    return text, {"lanes": 4, "n_vertices": n_vertices, "n_edges": n_edges, "index_sets": index_sets,
+                  "batched_iters": 10, "lane_iters": 40, "bounds": [1.0] * 4, "feasible": [True] * 4}
+
+
+@pytest.mark.parametrize("family", ["match", "vcover"])
+def test_a_matvec_that_does_not_scatter_keeps_the_scatter_roofline(family, monkeypatch):
+    """Compiled with XLA's scatter-add and with a gather-reduce of the same
+    operator under the same scope, the batched program puts instructions
+    under ``incidence.scatter`` both times, a scatter only the first time;
+    the bytes are the same, and the roofline reads a positive share of each."""
+    import jax
+
+    from perfbench.metrics import scatter_roofline
+    from repro.core.operators import Incidence
+    from repro.tracecheck.hlo_ir import parse_hlo
+    from repro.utils import hlo
+
+    jax.clear_caches()
+    variants = [_compiled_batch(family)]
+    monkeypatch.setattr(Incidence, "matvec", jax.named_scope("incidence.scatter")(_gather_reduce_matvec))
+    variants.append(_compiled_batch(family))
+    metric = [m for m in BENCH["per_layer"] if m["name"] == "scatter_roofline"]
+    program = "jit__feasibility_batch(1)"
+    moved, reads = [], []
+    for (text, launch), scatters in zip(variants, (True, False)):
+        ops = {op.name: op.kind for comp in parse_hlo(text).comps.values() for op in comp.ops}
+        scoped = trace.op_scopes(text, ("incidence.scatter",))
+        assert scoped and any(ops[name] == "scatter" for name in scoped) == scatters
+        scopes = ("incidence.scatter", "incidence.gather", "mwu.linesearch")  # the copy reads as the program's own
+        assert trace.op_scopes(text, scopes) == hlo.op_scopes(text, scopes)
+        s = trace.Summary(window_s=1.0, busy_s=len(ops) * 1e-6, n_devices=1, idle_by_span_s={},
+                          op_s={(program, f"%{name} = f32[] {kind}()"): 1e-6 for name, kind in ops.items()},
+                          programs={program: text})
+        assert s.scope_seconds("incidence.scatter") == pytest.approx(len(scoped) * 1e-6)
+        run = _roofline_run(s, family)
+        run.launches[:] = [launch]
+        moved.append(scatter_roofline.moved(run, launch))
+        reads.append(harness.read_metrics(run, metric)["scatter_roofline"]["value"])
+    assert moved[0] == moved[1] and all(r > 0 for r in reads)
 
 
 # -- the yardstick: bytes, peaks, generator, reference -----------------------
