@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .operators import LinOp
+from .operators import LinOp, with_endpoint_order
 
 __all__ = ["MPCOptions", "mpc_solve"]
 
@@ -66,6 +66,7 @@ def _mpc_iter(P: LinOp, C: LinOp, x, mu, beta, x_max, c_mask, has_mask):
 
 def mpc_solve(P: LinOp, C: LinOp, opts: MPCOptions = MPCOptions(), c_mask=None):
     """Run MPCSolver; returns (x, trace dict) with per-iteration violation."""
+    P, C = with_endpoint_order(P), with_endpoint_order(C)  # sorted once, not per iteration
     m = P.shape[0] + C.shape[0]
     n = P.shape[1]
     dt = jnp.result_type(float)  # canonical float: f64 iff x64 is enabled

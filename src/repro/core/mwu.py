@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import dispatch as _kd
-from .operators import LinOp
+from .operators import LinOp, with_endpoint_order
 from .smoothing import smax_and_weights, smin_and_weights
 from .stepsize import STEP_RULES, StepSizeResult
 
@@ -310,6 +310,8 @@ def _run(
 
 
 def _run_inner(P: LinOp, C: LinOp, opts: MWUOptions, pm, cm, trace: bool, axis=None, init_cols=None):
+    # sort the scatter direction's endpoints once per launch, not per iteration
+    P, C = with_endpoint_order(P), with_endpoint_order(C)
     m = P.shape[0] + C.shape[0]
     dt = jnp.promote_types(P.colmax().dtype, C.colmax().dtype)
     dt = dt if jnp.issubdtype(dt, jnp.floating) else jnp.float32

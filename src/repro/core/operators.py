@@ -15,8 +15,12 @@ underlying graph — storing them explicitly would double (M) or quadruple
 accumulations (scatter-add over endpoints); products with the transpose
 are gathers (``w[u] + w[v]``), which is the direction the paper fuses.
 
-TPU adaptation (DESIGN.md §3): the scatter direction lowers to XLA
-scatter-add over a sorted edge list; the gather direction dispatches at
+TPU adaptation: the scatter direction of ``Incidence`` is a reduction
+over its endpoint order (:func:`endpoint_order`, computed once per MWU
+launch): the edge values gathered in vertex order, each vertex's run
+summed by a segmented scan. It emits no XLA scatter and no per-call sort.
+The other operators' scatter directions stay XLA scatter-adds. The
+gather direction dispatches at
 trace time through ``repro.kernels.dispatch`` — when the active
 :class:`~repro.kernels.dispatch.KernelPolicy` selects the pallas
 backend (``MWUOptions.kernel_backend``, resolved host-side by the solve
@@ -42,11 +46,12 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..kernels import dispatch as _kd
 
@@ -55,6 +60,9 @@ __all__ = [
     "Dense",
     "Coo",
     "Incidence",
+    "EndpointOrder",
+    "endpoint_order",
+    "with_endpoint_order",
     "AdjacencyPlusId",
     "VertexEdgePair",
     "InterweavedId",
@@ -192,6 +200,106 @@ class Coo(LinOp):
         return int(self.rows.shape[0])
 
 
+ROW = 128  # slots per row of the segmented sum: one TPU vector row of lanes
+
+
+class EndpointOrder(NamedTuple):
+    """The 2E endpoint slots ``concat(u, v)`` of an edge list, sorted by vertex.
+
+    * ``slot_edge`` (S,) int32: the edge whose value fills each slot. S is
+      2E plus at least one pad slot, and a pad slot holds E, which reads 0;
+    * ``starts`` (S,) bool: the slot begins a vertex's run (so does the
+      first pad slot);
+    * ``seg_end`` (n,) int32: each vertex's last slot; a vertex with no
+      edge points at the first pad slot, whose run sums to 0.
+    """
+
+    slot_edge: jax.Array
+    starts: jax.Array
+    seg_end: jax.Array
+
+
+@jax.named_scope("incidence.scatter")
+def endpoint_order(u, v, n_vertices: int) -> EndpointOrder:
+    """Sort the endpoints of ``(u, v)`` by vertex (stable: by slot within one).
+
+    It serves the scatter direction alone, so its time counts under that
+    direction's scope, once per launch.
+    """
+    E = u.shape[0]
+    keys = jnp.concatenate([u, v]).astype(jnp.int32)
+    order = jnp.argsort(keys, stable=True).astype(jnp.int32)
+    pad = -(-(2 * E + 1) // ROW) * ROW - 2 * E
+    sorted_keys = jnp.concatenate([keys[order], jnp.full((pad,), n_vertices, jnp.int32)])
+    slot_edge = jnp.concatenate([jnp.where(order < E, order, order - E), jnp.full((pad,), E, jnp.int32)])
+    starts = jnp.concatenate([jnp.ones((1,), bool), sorted_keys[1:] != sorted_keys[:-1]])
+    last = jnp.full((n_vertices,), -1, jnp.int32).at[sorted_keys[: 2 * E]].max(
+        jnp.arange(2 * E, dtype=jnp.int32), indices_are_sorted=True
+    )
+    return EndpointOrder(slot_edge, starts, jnp.where(last < 0, 2 * E, last))
+
+
+def _scan_rows(f, v):
+    """Segmented inclusive sums along each row, by log2(ROW) lane shifts.
+
+    Returns ``(started, sums)``: whether a run starts at or before each
+    slot of its row, and the sum from that start (or the row's first slot).
+    """
+    k = 1
+    while k < ROW:
+        shift = ((0, 0), (k, 0))
+        f_prev = jnp.pad(f[:, :-k], shift, constant_values=False)
+        v_prev = jnp.pad(v[:, :-k], shift)
+        v = jnp.where(f, v, v + v_prev)
+        f = f | f_prev
+        k *= 2
+    return f, v
+
+
+def segmented_sum(starts, vals, at):
+    """The inclusive sums of ``vals`` (1-D) that restart wherever ``starts``, read at ``at``.
+
+    Rows of :data:`ROW` slots are scanned along the row; the rows' last
+    sums are scanned the same way, one level per factor of ROW, for the
+    carry into a row's slots before its first start. Every partial sum
+    restarts at a run's start, so a long run does not pay for the
+    cancellation of a difference of prefix sums.
+    """
+    n = vals.shape[0]
+    rows = -(-n // ROW)
+    pad = rows * ROW - n
+    # pin the rows row-major, which vmap keeps with its lanes major: left
+    # to XLA, 4 vmapped lanes went minor, padded to 128 lanes, and the
+    # scan moved 32 times its bytes (a v5e ran a Graph500-13 vertex
+    # cover solve 77% slower than with the scatter-add)
+    rowwise = Layout(major_to_minor=(0, 1))
+    f = with_layout_constraint(jnp.pad(starts, (0, pad), constant_values=True).reshape(rows, ROW), rowwise)
+    v = with_layout_constraint(jnp.pad(vals, (0, pad)).reshape(rows, ROW), rowwise)
+    started, s = _scan_rows(f, v)
+    row, lane = at // ROW, at % ROW
+    out = s[row, lane]
+    if rows > 1:
+        total = segmented_sum(f.any(axis=1), s[:, -1], jnp.arange(rows - 1))
+        carry = jnp.concatenate([jnp.zeros((1,), vals.dtype), total])
+        out = out + jnp.where(started[row, lane], 0, carry[row])
+    return out
+
+
+def with_endpoint_order(op):
+    """``op`` with every :class:`Incidence` inside it given its endpoint order.
+
+    Walks any operator pytree (wrappers, stacks, transposes, the mesh's
+    slab wrappers). Call it once per launch, outside the MWU loop, so the
+    loop's scatter direction sorts nothing.
+    """
+    def add(o):
+        if isinstance(o, Incidence) and o.order is None:
+            return dataclasses.replace(o, order=endpoint_order(o.u, o.v, o.n_vertices))
+        return o
+
+    return jax.tree_util.tree_map(add, op, is_leaf=lambda o: isinstance(o, Incidence))
+
+
 @register_op
 @dataclass
 class Incidence(LinOp):
@@ -200,6 +308,9 @@ class Incidence(LinOp):
     Stored implicitly as the edge list. Optional per-edge weights scale
     the column (both endpoints share the weight — weighted graphs).
     ``edge_mask`` zeroes padded edges (distributed layouts pad).
+    ``order`` is the endpoint order of the scatter direction
+    (:func:`with_endpoint_order`); without it ``matvec`` computes one in
+    place.
     """
 
     u: jax.Array  # (E,) int32 endpoint 0
@@ -207,6 +318,7 @@ class Incidence(LinOp):
     n_vertices: int = static_field(default=0)
     weights: Any = None  # optional (E,)
     edge_mask: Any = None  # optional (E,) bool
+    order: Any = None  # optional EndpointOrder
 
     @property
     def shape(self):
@@ -221,10 +333,15 @@ class Incidence(LinOp):
 
     @jax.named_scope("incidence.scatter")
     def matvec(self, x):
-        # y_u += x_e ; y_v += x_e  (scatter direction)
+        # y_u += x_e ; y_v += x_e  (scatter direction), as a sum over each
+        # vertex's run of edge values in endpoint order
+        _kd.note_scatter(ordered=self.order is not None)
+        order = self.order if self.order is not None else endpoint_order(self.u, self.v, self.n_vertices)
         xw = x * self._w(x.dtype)
-        out = jnp.zeros((self.n_vertices,), dtype=x.dtype)
-        return out.at[self.u].add(xw).at[self.v].add(xw)
+        if xw.shape[0] == 0:  # take() refuses an empty axis; no edges sum to 0
+            return jnp.zeros((self.n_vertices,), x.dtype)
+        vals = jnp.take(xw, order.slot_edge, mode="fill", fill_value=0)
+        return segmented_sum(order.starts, vals, order.seg_end)
 
     @jax.named_scope("incidence.gather")
     def rmatvec(self, y):

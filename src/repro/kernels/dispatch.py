@@ -184,12 +184,22 @@ OVER_VMEM = "gather: vertex vector over the VMEM limit"
 PROBE_CANCELS = "probe: a difference of two lse cancels below f64"
 
 
-def _note(op: str, impl: str, reason: str | None = None) -> None:
+def _note(op: str, impl: str, reason: str | None = None, key: str = "fallback") -> None:
     d = _STATS.setdefault(op, {"pallas": 0, "xla": 0})
     d[impl] += 1
     if reason is not None:
-        fb = d.setdefault("fallback", {})
+        fb = d.setdefault(key, {})
         fb[reason] = fb.get(reason, 0) + 1
+
+
+def note_scatter(ordered: bool) -> None:
+    """Count one traced incidence scatter direction under ``stats()["scatter"]``.
+
+    Its ``"order"`` entry says where the endpoint order came from:
+    ``"ordered"`` (computed once per launch) or ``"inline"`` (computed in
+    place, outside a solve).
+    """
+    _note("scatter", "xla", "ordered" if ordered else "inline", key="order")
 
 
 def reset_stats() -> None:

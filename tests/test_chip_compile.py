@@ -11,7 +11,8 @@ device's memory), at no chip time.
   (edgefactor 16, seed 1: 1,048,576 vertices, 15,702,278 edges) with
   four bounds, the ``Solver.solve`` default; dense-sub at scale 10,
   the largest that compiles in seconds (its compile time and temp bytes
-  grow with the edge count: ROADMAP §1.5);
+  grow with the edge count: ROADMAP §1.5); match's and vcover's MWU
+  loops hold no scatter and no sort;
 * one case per Pallas kernel that runs on the chip, at 2^20 and at
   15.7M elements.
 
@@ -115,9 +116,24 @@ def test_solve_batch_compiles_for_v5e(one_chip, family, size):
         seconds = time.perf_counter() - t0
     mem = compiled.memory_analysis()
     used = mem.temp_size_in_bytes + mem.argument_size_in_bytes + mem.output_size_in_bytes
-    print(f"{family} {size}: compile {seconds:.1f} s, {used / 2**30:.2f} GiB")
+    print(f"{family} {size}: compile {seconds:.1f} s, {used / 2**30:.2f} GiB, temp {mem.temp_size_in_bytes} B")
     assert used < 16 * 10**9, f"{family}: {used} bytes do not fit one v5e chip"
-    assert "tpu_custom_call" not in compiled.as_text()  # no Pallas under XLA
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # no Pallas under XLA
+    if family in ("match", "vcover"):
+        # the incidence scatter direction reduces over an endpoint order
+        # sorted once per launch: the MWU loop scatters and sorts nothing
+        assert _loop_op_kinds(text) & {"scatter", "sort"} == set()
+
+
+def _loop_op_kinds(text: str) -> set[str]:
+    """The kinds of every op in the program's while bodies (nested calls too)."""
+    from repro.tracecheck.hlo_ir import parse_hlo, reachable, while_ops
+
+    mod = parse_hlo(text)
+    comps = {c for w in while_ops(mod) for c in reachable(mod.comps, w["body"])}
+    assert comps, "no while body in the program"
+    return {op.kind for c in comps for op in mod.comps[c].ops}
 
 
 def _kernel_call(name: str, n: int, sharding):

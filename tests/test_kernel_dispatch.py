@@ -403,3 +403,22 @@ def test_solve_batch_pallas_backend_vmaps():
         out[be] = np.asarray(res.status)
     # batched lanes share the vmapped XLA rule → identical feasibility calls
     np.testing.assert_array_equal(out["pallas"], out["xla"])
+
+
+@pytest.mark.parametrize("family", ["match", "vcover"])
+def test_every_in_loop_incidence_scatter_is_ordered(family):
+    """A solve traces each incidence scatter direction over the endpoint order
+    sorted once per launch (``ordered``); a product outside a solve sorts in
+    place (``inline``)."""
+    from repro.api import MWUOptions, Solver
+
+    jax.clear_caches()  # the counts are taken at trace time
+    prob = build(family, grid2d(5))
+    sol = Solver(MWUOptions(eps=0.2, step_rule="newton", max_iter=5000), batch_width=2).solve(prob)
+    assert sol.feasible
+    assert set(kd.stats()["scatter"]["order"]) == {"ordered"}
+    kd.reset_stats()
+    inc = prob.P if family == "match" else prob.C.inner
+    assert isinstance(inc, ops.Incidence)
+    inc.matvec(jnp.ones(inc.shape[1]))
+    assert kd.stats()["scatter"]["order"] == {"inline": 1}

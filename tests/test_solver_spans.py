@@ -154,18 +154,21 @@ def test_solve_writes_its_spans_to_the_profiler_trace(tmp_path):
 @pytest.mark.parametrize("family", ["match", "vcover"])
 def test_named_scopes_reach_the_hlo_metadata(family):
     """Every stage's scope is in the batched program's op names, and the
-    operator scopes follow the data: scatter-adds under ``incidence.scatter``
-    and gathers under ``incidence.gather`` (vertex cover's transposed
-    incidence too)."""
+    operator scopes follow the data: the scatter direction, a reduction over
+    an ordered gather with no scatter in the loop, under ``incidence.scatter``
+    and the gather direction under ``incidence.gather`` (vertex cover's
+    transposed incidence too)."""
     p = _lp(family, scale=4)
     hlo = Solver(MWUOptions(eps=0.1)).lower_batch(p, np.array([1.0, 2.0])).as_text(dialect="hlo", debug_info=True)
     names = re.findall(r'op_name="([^"]*)"', hlo)
     for scope in SCOPES:
         assert any(f"/{scope}/" in n for n in names), scope
     loop = [n for n in names if "/while/body/" in n]
-    assert all("/incidence.scatter/" in n for n in loop if n.endswith("/scatter-add"))
-    assert all("/incidence.gather/" in n for n in loop if n.endswith("/gather"))
-    assert any(n.endswith("/scatter-add") for n in loop) and any(n.endswith("/gather") for n in loop)
+    assert not any("scatter" in n.rsplit("/", 1)[-1] for n in loop)
+    gathers = [n for n in loop if n.endswith("/gather")]
+    assert all("/incidence.scatter/" in n or "/incidence.gather/" in n for n in gathers)
+    for scope in ("incidence.scatter", "incidence.gather"):
+        assert any(f"/{scope}/" in n for n in gathers), scope
 
 
 @pytest.mark.parametrize("family", ["match", "vcover"])
