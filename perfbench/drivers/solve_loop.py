@@ -15,6 +15,9 @@ run does the same whole passes, so the work does not depend on the order.
 Each solve ends when its certified x is on the host. After the window,
 every solve's x is checked in float64 against the exact optimum of its
 graph, and its bound search's final bracket is read from its launches.
+
+A driver for another solver subclasses ``Driver`` and gives its own
+``make_solver`` and ``place``.
 """
 from __future__ import annotations
 
@@ -38,17 +41,25 @@ class Driver:
         self.solves: list[dict] = []
         self.answers: list[tuple] = []  # (graph, certified x or None, certified bound, launches)
 
-    def setup(self) -> None:
+    def make_solver(self):
+        """The solver the window drives: one chip's ``Solver``."""
         from repro.api import Solver
 
+        return Solver(lp.options(self.config), batch_width=self.traffic["batch_width"])
+
+    def place(self, problem):
+        """``problem`` where the solver reads it: on the cell's one chip."""
+        return jax.device_put(problem, self.devices[0])
+
+    def setup(self) -> None:
         cfg, tr = self.config, self.traffic
-        self.solver = Solver(lp.options(cfg), batch_width=tr["batch_width"])
+        self.solver = self.make_solver()
         for s in tr["graph_seeds"]:
             n, u, v = lp.graph(cfg, cfg["scale"], s)
             self.graphs.append((n, u, v))
             p = lp.problem(cfg, n, u, v, f"kron-{cfg['scale']}-{s}")
             self.ends.append((float(p.lo), float(p.hi), p.feasible_side))
-            self.problems.append(jax.device_put(p, self.devices[0]))
+            self.problems.append(self.place(p))
         self.counter = LaunchCounter(self.solver)
         for p in self.problems:
             jax.block_until_ready(self.solver.solve(lp.easy_problem(p, self.devices[0])).last_result)

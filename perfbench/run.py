@@ -9,9 +9,9 @@ cell's driver measures for ``--seconds`` seconds; then every answer the
 window produced is checked against the plain reference. With ``--trace 1``
 the JAX profiler records the window's launches up to the first that starts
 ``TRACE_SECONDS`` into it, with each program's HLO, and the per-layer
-metrics are read from that trace (device ops by the named scope their own
-program gives them) and from the whole window's counters; with
-``--trace 0`` the end-to-end metrics.
+metrics are read from that trace (on the planes of the chips the cell ran
+on; device ops by the named scope their own program gives them) and from
+the whole window's counters; with ``--trace 0`` the end-to-end metrics.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (with ``busy_s`` and
@@ -141,7 +141,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, rehearse: bool = Fal
     if traced:
         from perfbench import trace
 
-        run.trace = trace.summarize(trace.load(trace_dir))
+        run.trace = trace.summarize(trace.load(trace_dir), {d.id for d in devices})
         shutil.rmtree(trace_dir, ignore_errors=True)
         if run.trace is not None:
             device.update(busy_s=run.trace.busy_s, window_s=run.trace.window_s)

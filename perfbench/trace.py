@@ -11,6 +11,9 @@ programs (the HLO protos the profile keeps under the same names on its
 alone, so a test can hand it a synthetic trace:
 
 * the window is the ``bench.traced`` span, open while the profiler records;
+* the devices are the chips the run used: the profiler records a plane for
+  every TPU of the host, and ``/device:TPU:<n>`` is the chip whose JAX
+  device id is ``n``, so the other planes are left out;
 * busy time is the union of a device's op intervals inside the window,
   averaged over the devices; an op that only holds others (``while``,
   ``conditional``, ``call``, whose body ops are events of their own) is
@@ -37,7 +40,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import NamedTuple
 
-DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 METADATA_PLANE = "/host:metadata"
@@ -298,18 +301,24 @@ class Summary:
         return {"device_ops": [list(kv) for kv in ops], "idle_gaps": [list(kv) for kv in gaps]}
 
 
-def summarize(trace: Trace) -> Summary | None:
-    """Busy, op and idle times inside the ``bench.traced`` span, or None."""
+def summarize(trace: Trace, device_ids=None) -> Summary | None:
+    """Busy, op and idle times inside the ``bench.traced`` span, or None.
+
+    ``device_ids``: the JAX ids of the chips the run used; only their planes
+    are read (all planes where None).
+    """
     windows = [s for s in trace.spans if s.label == WINDOW_SPAN]
-    if not windows or not trace.devices:
+    planes = [ops for name, ops in trace.devices.items()
+              if device_ids is None or int(DEVICE_PLANE.match(name).group(1)) in device_ids]
+    if not windows or not planes:
         return None
     w0, w1 = windows[0].start_ns, windows[0].end_ns
     spans = SpanIndex([s for s in trace.spans if s.end_ns > w0 and s.start_ns < w1])
-    n = len(trace.devices)
+    n = len(planes)
     busy = 0.0
     op_s: dict[tuple[str, str], float] = defaultdict(float)
     idle: dict[str, float] = defaultdict(float)
-    for ops in trace.devices.values():
+    for ops in planes:
         clipped = []
         for iv in ops:
             a, b = max(iv.start_ns, w0), min(iv.end_ns, w1)

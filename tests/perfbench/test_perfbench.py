@@ -7,6 +7,7 @@ import shutil
 
 import numpy as np
 import pytest
+from cell_runs import drive
 
 from perfbench import graph500, harness, lp, opbytes, peaks, reference, trace
 from perfbench.instrument import LaunchCounter, incidence_dims
@@ -96,44 +97,14 @@ def test_a_new_cell_is_new_files_and_entries(tmp_path):
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
-def test_nothing_compiles_inside_the_window(cell, monkeypatch):
+def test_nothing_compiles_inside_the_window(cell):
     """Set-up warms every program a solve runs, the lane splits among them."""
-    import logging
-
-    from perfbench import run
-
     c = harness.resolve(cell)
     c.config = dict(c.config, scale={"match": 8, "vcover": 7}[c.config["lp"]], max_iter=4322)
     c.traffic = dict(c.traffic, graph_seeds=[1, 2])
-    module = harness.driver(c)
-    monkeypatch.setattr(harness, "driver", lambda _: module)
-    compiles, in_window = [], [False]
-
-    class Log(logging.Handler):
-        def emit(self, record):
-            if in_window[0] and record.getMessage().startswith("Compiling"):
-                compiles.append(record.getMessage()[:120])
-
-    window = module.Driver.window
-
-    def watched(self, seconds):
-        in_window[0] = True
-        try:
-            return window(self, seconds)
-        finally:
-            in_window[0] = False
-
-    monkeypatch.setattr(module.Driver, "window", watched)
-    log = logging.getLogger("jax._src.interpreters.pxla")
-    handler = Log(level=logging.DEBUG)
-    log.addHandler(handler)
-    monkeypatch.setattr(log, "level", logging.DEBUG)
-    try:
-        result = run.run_cell(c, 2**31 + 13, 0.0, traced=False, rehearse=True)
-    finally:
-        log.removeHandler(handler)
+    result = drive(c, seed=2**31 + 13, watch_compiles=True)
     assert result["correct"] and result["attempted"] == 2
-    assert compiles == []
+    assert result["compiles"] == []
 
 
 def test_unknown_workload_is_an_error():
@@ -209,6 +180,24 @@ def test_trace_reduction_gives_known_times():
     assert b["idle_gaps"][0] == ["outside", pytest.approx(150e-9)]
 
 
+def test_only_the_planes_of_the_runs_chips_are_read():
+    """A one-chip cell on a four-chip host: the profiler records all four
+    planes, the run's chip (JAX id 2) ran every op, and the reading is that
+    plane's alone, not diluted by three idle chips."""
+    t = synthetic_trace()
+    ops = t.devices["/device:TPU:0"]
+    t.devices = {f"/device:TPU:{i}": ops if i == 2 else [] for i in range(4)}
+    s = trace.summarize(t, {2})
+    assert s.n_devices == 1
+    assert s.busy_s == pytest.approx(400e-9)
+    assert s.idle_share == pytest.approx(1 - 400 / 1000)
+    assert s.scope_seconds("incidence.scatter") == pytest.approx(200e-9)
+    assert s.idle_by_span_s == pytest.approx({"bench.launch": 100e-9, "bench.solve": 200e-9, "outside": 300e-9})
+    every = trace.summarize(t)
+    assert every.n_devices == 4 and every.busy_s == pytest.approx(s.busy_s / 4)
+    assert trace.summarize(t, {7}) is None  # no plane of the run's chips: nothing is read
+
+
 def test_two_programs_that_reuse_instruction_names_keep_their_own_scopes():
     """Two graphs of a pool compile to programs that differ in shapes alone and
     reuse instruction names; each op's time lands on its own program's scope."""
@@ -228,14 +217,34 @@ def test_two_programs_that_reuse_instruction_names_keep_their_own_scopes():
     assert s.breakdown()["device_ops"][0] == ["%fusion.118", pytest.approx(250e-9)]
 
 
-def _roofline_run(summary, lp_kind="match") -> harness.RunRecord:
+def _roofline_run(summary, lp_kind="match", n_devices=1) -> harness.RunRecord:
     cell = harness.resolve({"match": "g500-match.s16", "vcover": "g500-vcover.s13"}[lp_kind])
-    run = harness.RunRecord(cell, "TPU v5 lite", 1)
+    run = harness.RunRecord(cell, "TPU v5 lite", n_devices)
     run.launches = [{"lanes": 4, "n_vertices": 64, "n_edges": 500, "index_sets": 1, "batched_iters": 10,
                      "lane_iters": 40, "bounds": [1.0] * 4, "feasible": [True] * 4}]
     run.solves = [{"launches": run.launches}]
     run.traced, run.trace = 1, summary
     return run
+
+
+@pytest.mark.parametrize("op", ["scatter", "gather"])
+def test_a_roofline_is_of_the_chips_the_run_used(op):
+    """The same launches and per-chip scope seconds on four chips read a
+    quarter of the share they read on one: the whole graph's bytes against
+    four chips' bandwidth."""
+    iv = trace.Interval
+    t = trace.Trace(devices={"/device:TPU:0": [iv("%fusion.1 = f32[4,64]", 100, 300, SOLVE)]},
+                    spans=[iv(trace.WINDOW_SPAN, 0, 1000)],
+                    programs={SOLVE: hlo_module(SOLVE, {"fusion.1": f"incidence.{op}"})})
+    s = trace.summarize(t)
+    metric = [m for m in BENCH["per_layer"] if m["name"] == f"{op}_roofline"]
+    one, four = (harness.read_metrics(_roofline_run(s, n_devices=n), metric)[f"{op}_roofline"]["value"]
+                 for n in (1, 4))
+    per_call = opbytes.incidence_bytes(64, 500, 4)  # _roofline_run's launch: 10 batched iterations
+    start = opbytes.incidence_bytes(64, 500, 1) if op == "scatter" else 0  # match's y = Px0
+    hbm = peaks.peaks("TPU v5 lite")["hbm_bytes_per_s"]
+    assert one == pytest.approx(100.0 * (10 * per_call + start) / 200e-9 / hbm)
+    assert four == pytest.approx(one / 4)
 
 
 def test_no_op_under_a_scope_reads_none_not_zero():
