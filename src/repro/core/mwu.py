@@ -136,7 +136,8 @@ def init_x(P: LinOp, eps: float, dtype, n_cols: int | None = None, axis=None) ->
     x = eps / (n * safe)
     fallback = jnp.min(jnp.where(cm > 0, x, jnp.inf))
     if axis is not None:
-        fallback = jax.lax.pmin(fallback, axis)
+        with jax.named_scope("pod.psum"):
+            fallback = jax.lax.pmin(fallback, axis)
     fallback = jnp.where(jnp.isfinite(fallback), fallback, eps / n)
     return jnp.where(cm > 0, x, fallback).astype(dtype)
 
@@ -186,7 +187,8 @@ def _iteration(P: LinOp, C: LinOp, eta, scale, step_fn, ls_eps, p_mask, c_mask, 
 
     max_d = jnp.max(d)
     if axis is not None:
-        max_d = jax.lax.pmax(max_d, axis)
+        with jax.named_scope("pod.psum"):
+            max_d = jax.lax.pmax(max_d, axis)
     infeasible_dir = max_d <= 0  # line 8
 
     # step images (line 10) — the second SpMV pair

@@ -65,6 +65,14 @@ __all__ = [
 
 
 # ------------------------------------------------------------- operators --
+def _pod_psum(x, axis):
+    """``lax.psum`` over ``axis``, under the ``pod.psum`` scope that names
+    the pod path's collectives in a trace (``core.mwu`` puts its ``pmax``
+    and ``pmin`` there too)."""
+    with jax.named_scope("pod.psum"):
+        return lax.psum(x, axis)
+
+
 @register_op
 @dataclass
 class PodSum(LinOp):
@@ -87,7 +95,7 @@ class PodSum(LinOp):
         return self.inner.shape
 
     def matvec(self, x):
-        return lax.psum(self.inner.matvec(x), self.axis)
+        return _pod_psum(self.inner.matvec(x), self.axis)
 
     def rmatvec(self, y):
         return self.inner.rmatvec(y)
@@ -139,7 +147,7 @@ class SlabCols(LinOp):
         return lax.dynamic_slice(fullp, (start,), (self.block,))
 
     def matvec(self, x):
-        return lax.psum(self.inner.matvec(self._embed(x)), self.axis)
+        return _pod_psum(self.inner.matvec(self._embed(x)), self.axis)
 
     def rmatvec(self, y):
         return self._extract(self.inner.rmatvec(y))

@@ -31,10 +31,14 @@ is thereby distributed for free.
 """
 from __future__ import annotations
 
+import dataclasses
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..api.problem import Problem
 from ..api.solver import Solver
 from ..core.mwu import _run
 from ..kernels import dispatch as _kd
@@ -53,6 +57,20 @@ from .shard import (
 )
 
 __all__ = ["DistSolver"]
+
+# the leaves a copy made with other ``lo``/``hi`` shares with its original
+_GRAPH_FIELDS = ("P", "C", "c", "p_mask", "c_mask")
+
+
+def _graph_key(problem) -> tuple[int, ...]:
+    return tuple(id(getattr(problem, f)) for f in _GRAPH_FIELDS)
+
+
+class _Placement(NamedTuple):
+    problem: Problem  # padded, on the mesh
+    ncols: int  # the variables of the problem as given
+    c: np.ndarray | None  # its objective on the host, over those variables
+
 
 # jitted shard_map callables keyed on everything static about a launch;
 # rebuilding the closure per call would retrace/recompile every time.
@@ -110,6 +128,11 @@ class DistSolver(Solver):
     to the plain ``Solver``, so callers can hold a single solver type and
     scale by swapping the plan.
 
+    ``place`` pads a problem and puts it on the mesh once, for every
+    later solve of it (or of a copy with other ``lo``/``hi``); a problem
+    that was not placed is padded and put there at its first launch, and
+    again whenever another problem came between.
+
     ``dist_stats`` counts launches / lanes / MWU iterations and (for
     pod-sharded plans) an estimate of psum rounds — 3 collectives per
     iteration (dy, dz, pmax) plus init (y, z, pmin) — surfaced by
@@ -132,6 +155,38 @@ class DistSolver(Solver):
         # of the last launch: the bound search's launches share one
         # problem, which is padded and copied to the mesh only once
         self._placed = None
+        # what ``place`` returned, by ``_graph_key``
+        self._placements: dict[tuple[int, ...], _Placement] = {}
+
+    def place(self, problem):
+        """``problem`` padded and put on the mesh, for every later solve of it.
+
+        Pads the edge slab (edge_slab mode) and ``device_put``s each leaf
+        with the sharding its launches read it with. A launch of the
+        returned problem, or of a ``dataclasses.replace`` of it that
+        changes only ``lo`` and ``hi``, pads and transfers nothing; its
+        ``x`` and objective stay over the problem's own variables. The
+        solver holds what it placed for as long as it lives.
+        """
+        if self._placement(problem) is not None:
+            return problem
+        with jax.profiler.TraceAnnotation("dist.place", problem=problem.name):
+            mode = pod_mode(problem)
+            placed, ncols = self._pad(problem, mode, 1.0, False)
+            specs = problem_specs(placed, mode, False)
+            placed = jax.device_put(placed, sharding_for(self.plan.build(), specs))
+            c = None if problem.c is None else np.asarray(problem.c)
+        self._placements[_graph_key(placed)] = _Placement(placed, ncols, c)
+        return placed
+
+    def _pad(self, problem, mode, bound, batched_problem):
+        """(``problem`` padded to the plan's slabs, its own column count)."""
+        if mode == "edge_slab":
+            return slab_pad_problem(problem, self.plan.pod)
+        return problem, global_columns(problem, bound, batched_problem)
+
+    def _placement(self, problem, batched_problem: bool = False) -> _Placement | None:
+        return None if batched_problem else self._placements.get(_graph_key(problem))
 
     # -- feasibility primitives (everything else is inherited) ---------
     def _prepare_launch(self, problem, bounds, batched_problem: bool) -> dict:
@@ -149,14 +204,15 @@ class DistSolver(Solver):
         mode = pod_mode(problem)
         pad = (-B) % plan.data
         placed = self._placed
-        if placed is not None and placed[0] is problem and placed[1] == (batched_problem, pad):
+        placement = self._placement(problem, batched_problem)
+        if placement is not None:
+            # the launch reads neither lo nor hi: the copy placed serves
+            problem, ncols = placement.problem, placement.ncols
+        elif placed is not None and placed[0] is problem and placed[1] == (batched_problem, pad):
             problem, ncols = placed[2], placed[3]
         else:
             given = problem
-            if mode == "edge_slab":
-                problem, ncols = slab_pad_problem(problem, plan.pod)
-            else:
-                ncols = global_columns(problem, np.asarray(bounds)[0], batched_problem)
+            problem, ncols = self._pad(problem, mode, np.asarray(bounds)[0], batched_problem)
             if pad and batched_problem:
                 problem = jax.tree.map(
                     lambda a: jnp.concatenate(
@@ -261,6 +317,12 @@ class DistSolver(Solver):
     def dist_stats(self) -> dict:
         self._count_launch()
         return self._dist_stats
+
+    def _certify(self, problem, best, best_bound, stats, traces, spans):
+        placement = self._placement(problem)
+        if placement is not None:  # the objective over the problem's own edges
+            problem = dataclasses.replace(problem, c=placement.c)
+        return super()._certify(problem, best, best_bound, stats, traces, spans)
 
     def feasible(self, problem, bound=None, trace: bool = False):
         """One feasibility solve, pod-sharded when the plan is multi-device.

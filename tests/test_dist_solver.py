@@ -406,3 +406,96 @@ def test_pallas_pack_active_under_shard_map():
     assert d["pallas_gather_delta"] > 0, "Pallas pack fell back to XLA under shard_map"
     assert d["status"] == d["ref_status"]
     np.testing.assert_allclose(d["max_px"], d["ref_max_px"], rtol=5e-3, atol=5e-3)
+
+
+def test_placed_graphs_launch_without_padding_or_transfer(tmp_path):
+    """After ``DistSolver.place``, solves of two placed graphs in turn, and of
+    a copy of one with another bracket, pad and transfer nothing and give
+    what the same solves give without ``place``; each placement is one
+    ``dist.place`` span in the profiler's trace, outside every solve."""
+    out = run_sub(
+        f"""
+        import dataclasses, json
+        from pathlib import Path
+        import jax
+        import numpy as np
+        from jax.profiler import ProfileData
+        from repro.api import MWUOptions
+        from repro.dist import DistSolver, MeshPlan
+        from repro.dist import solver as dist_solver
+        from repro.graphs.generators import erdos
+        from repro.graphs.problems import matching_lp
+
+        probs = [matching_lp(erdos(60, 201, seed=1)), matching_lp(erdos(50, 150, seed=2))]
+
+        def rebracket(p):
+            return dataclasses.replace(p, lo=float(p.lo) * 0.9, hi=float(p.hi) * 1.1)
+
+        def solve_all(solver, ps):
+            sols = [solver.solve(p) for p in ps + ps[:1] + [rebracket(ps[1])]]
+            return [[np.asarray(s.x).tolist(), s.bound, s.objective, s.feasibility_calls] for s in sols]
+
+        opts = MWUOptions()
+        plan = MeshPlan(pod=4, data=1)
+        want = solve_all(DistSolver(opts, plan=plan), probs)
+        solver = DistSolver(opts, plan=plan)
+        jax.profiler.start_trace({str(tmp_path)!r})
+        placed = [solver.place(p) for p in probs]
+        calls = []
+        pad, put = dist_solver.slab_pad_problem, jax.device_put
+        dist_solver.slab_pad_problem = lambda *a: calls.append("pad") or pad(*a)
+        jax.device_put = lambda *a, **k: calls.append("put") or put(*a, **k)
+        got = solve_all(solver, placed)
+        again = solver.place(placed[0]) is placed[0]
+        jax.profiler.stop_trace()
+        data = ProfileData.from_file(str(next(Path({str(tmp_path)!r}).rglob("*.xplane.pb"))))
+        spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                 for plane in data.planes if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events
+                 if ev.name in ("dist.place", "solver.solve")]
+        u = placed[0].P.u
+        print(json.dumps({{
+            "want": want, "got": got, "calls": calls, "again": again, "spans": spans,
+            "u_shards": sorted(s.data.shape[0] for s in u.addressable_shards),
+        }}))
+        """,
+        devices=4,
+    )
+    d = json.loads(out.strip().splitlines()[-1])
+    assert d["calls"] == [] and d["again"]
+    assert d["u_shards"] == [51] * 4  # 201 edges padded to 204, a slab on each device
+    places = [s for s in d["spans"] if s[0] == "dist.place"]
+    solves = [s for s in d["spans"] if s[0] == "solver.solve"]
+    assert len(places) == 2 and len(solves) == 4
+    assert all(p[2] <= s[1] or s[2] <= p[1] for p in places for s in solves)
+    for (x, bound, objective, calls), (wx, wbound, wobjective, wcalls) in zip(d["got"], d["want"]):
+        assert len(x) == len(wx) and x == wx  # over the graph's own edges, bit for bit
+        assert (bound, objective, calls) == (wbound, wobjective, wcalls)
+
+
+def test_pod_collectives_lie_under_the_pod_psum_scope():
+    """Every all-reduce of the pod-4 match program (psum, pmax, pmin) is under
+    ``pod.psum``; the one-device program puts no op there."""
+    out = run_sub(
+        """
+        import json
+        from repro.dist import DistSolver, MeshPlan
+        from repro.graphs.generators import erdos
+        from repro.graphs.problems import matching_lp
+        from repro.tracecheck.hlo_ir import parse_hlo
+        from repro.utils.hlo import op_scopes
+
+        prob = matching_lp(erdos(60, 201, seed=1))
+        rows = {}
+        for name, plan in (("pod4", MeshPlan(pod=4, data=1)), ("one", MeshPlan())):
+            text = DistSolver(plan=plan).lower_batch(prob, [3.0, 7.0, 12.0, 20.0]).compile().as_text()
+            reduces = [op.name for c in parse_hlo(text).comps.values() for op in c.ops
+                       if op.kind.startswith("all-reduce")]
+            rows[name] = {"reduces": reduces, "scoped": sorted(op_scopes(text, ("pod.psum",)))}
+        print(json.dumps(rows))
+        """,
+        devices=4,
+    )
+    d = json.loads(out.strip().splitlines()[-1])
+    assert d["pod4"]["reduces"] and set(d["pod4"]["reduces"]) <= set(d["pod4"]["scoped"])
+    assert d["one"]["reduces"] == [] and d["one"]["scoped"] == []
